@@ -6,7 +6,9 @@ report comparisons/sec/rank (the paper's right-hand graphs; flat = ideal).
 
 Runs in a subprocess with 8 virtual CPU devices (one jax startup for the
 whole sweep); the measured efficiencies are structural (ring + round-robin
-overheads), with CPU compute standing in for the GPU mGEMM.
+overheads), with CPU compute standing in for the GPU mGEMM.  On any other
+backend it refuses: the calling process already holds the accelerator, and
+a child that needs it would fail or hang.
 """
 from __future__ import annotations
 
@@ -22,6 +24,15 @@ CACHE = os.path.join(HERE, "..", "results", "scaling.json")
 
 
 def run_harness():
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"the scaling sweep runs on 8 virtual CPU devices in a child "
+            f"process; this process holds the {backend} backend, which the "
+            "child cannot share — run it on a CPU host (JAX_PLATFORMS=cpu)"
+        )
     env = dict(os.environ)
     src = os.path.join(HERE, "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

@@ -34,46 +34,85 @@ def _mix(x: int) -> int:
     return x ^ (x >> 31)
 
 
+def _mix_np(x: np.ndarray) -> np.ndarray:
+    """``_mix`` over a uint64 array (multiplication wraps mod 2**64)."""
+    x = x + np.uint64(_GOLD)
+    x = (x ^ (x >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
+    x = (x ^ (x >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
+    return x ^ (x >> np.uint64(31))
+
+
 def _value_bits(values: np.ndarray) -> np.ndarray:
     v = np.asarray(values)
     if v.dtype == np.float64:
-        return v.view(np.uint64).astype(object)
+        return v.view(np.uint64)
     if v.dtype == np.float32:
-        return v.view(np.uint32).astype(object)
+        return v.view(np.uint32)
     if v.dtype.itemsize == 2:  # float16 / bfloat16 (ml_dtypes) metric outputs
-        return v.view(np.uint16).astype(object)
+        return v.view(np.uint16)
     raise TypeError(f"unsupported dtype {v.dtype}")
+
+
+_LO32 = np.uint64(0xFFFFFFFF)
+_BLOCK = 1 << 24  # entries per partial sum: 32-bit limbs stay below 2**56
+
+
+def _raw_total(keys: np.ndarray, values) -> int:
+    """sum(mix(key) * (bits(value) + 1)) mod MOD over uint64 ``keys``.
+
+    Exact, in numpy: for 32- and 16-bit values both 32-bit halves of
+    ``mix(key)`` times ``bits + 1`` fit in uint64, and their 32-bit limbs
+    are summed in blocks small enough not to wrap.  64-bit values take the
+    per-entry Python-integer loop."""
+    keys = np.asarray(keys, np.uint64).ravel()
+    bits = _value_bits(values).ravel()
+    if bits.dtype == np.uint64:
+        total = 0
+        for k, b in zip(keys.tolist(), bits.tolist()):
+            total = (total + _mix(k) * (b + 1)) % MOD
+        return total
+    total = 0
+    for s in range(0, keys.size, _BLOCK):
+        mixed = _mix_np(keys[s:s + _BLOCK])
+        b1 = bits[s:s + _BLOCK].astype(np.uint64) + np.uint64(1)
+        for half, shift in ((mixed >> np.uint64(32), 32), (mixed & _LO32, 0)):
+            prod = half * b1
+            total += (
+                (int((prod >> np.uint64(32)).sum()) << (shift + 32))
+                + (int((prod & _LO32).sum()) << shift)
+            )
+    return total % MOD
+
+
+def _pair_keys(i, j) -> np.ndarray:
+    """(i, j) canonicalized to i < j, packed as lo << 32 | hi."""
+    i = np.asarray(i, np.int64)
+    j = np.asarray(j, np.int64)
+    lo = np.minimum(i, j).astype(np.uint64)
+    hi = np.maximum(i, j).astype(np.uint64)
+    return (lo << np.uint64(32)) | hi
+
+
+def _triple_keys(i, j, k) -> np.ndarray:
+    """(i, j, k) canonicalized ascending, packed 21 bits per index."""
+    idx = np.sort(
+        np.stack([np.asarray(i), np.asarray(j), np.asarray(k)], -1), -1
+    ).astype(np.uint64)
+    return (
+        (idx[..., 0] << np.uint64(42))
+        | (idx[..., 1] << np.uint64(21))
+        | idx[..., 2]
+    )
 
 
 def checksum_pairs(i, j, values) -> int:
     """Checksum of 2-way results. (i, j) canonicalized to i < j."""
-    i = np.asarray(i, np.int64)
-    j = np.asarray(j, np.int64)
-    lo = np.minimum(i, j)
-    hi = np.maximum(i, j)
-    keys = (lo.astype(object) << 32) | hi.astype(object)
-    bits = _value_bits(values)
-    total = 0
-    count = keys.size
-    for k, b in zip(keys.ravel(), bits.ravel()):
-        total = (total + _mix(int(k)) * (int(b) + 1)) % MOD
-    return (total + _mix(count)) % MOD
+    return combine([raw_pairs(i, j, values)])
 
 
 def checksum_triples(i, j, k, values) -> int:
     """Checksum of 3-way results. (i, j, k) canonicalized ascending."""
-    idx = np.sort(np.stack([np.asarray(i), np.asarray(j), np.asarray(k)], -1), -1)
-    keys = (
-        (idx[..., 0].astype(object) << 42)
-        | (idx[..., 1].astype(object) << 21)
-        | idx[..., 2].astype(object)
-    )
-    bits = _value_bits(values)
-    total = 0
-    count = keys.size
-    for key, b in zip(keys.ravel(), bits.ravel()):
-        total = (total + _mix(int(key)) * (int(b) + 1)) % MOD
-    return (total + _mix(count)) % MOD
+    return combine([raw_triples(i, j, k, values)])
 
 
 def combine(parts) -> int:
@@ -92,27 +131,10 @@ def combine(parts) -> int:
 
 def raw_pairs(i, j, values) -> tuple[int, int]:
     """Count-free partial checksum for combine()."""
-    i = np.asarray(i, np.int64)
-    j = np.asarray(j, np.int64)
-    lo = np.minimum(i, j)
-    hi = np.maximum(i, j)
-    keys = (lo.astype(object) << 32) | hi.astype(object)
-    bits = _value_bits(values)
-    total = 0
-    for k, b in zip(keys.ravel(), bits.ravel()):
-        total = (total + _mix(int(k)) * (int(b) + 1)) % MOD
-    return total, keys.size
+    keys = _pair_keys(i, j)
+    return _raw_total(keys, values), keys.size
 
 
 def raw_triples(i, j, k, values) -> tuple[int, int]:
-    idx = np.sort(np.stack([np.asarray(i), np.asarray(j), np.asarray(k)], -1), -1)
-    keys = (
-        (idx[..., 0].astype(object) << 42)
-        | (idx[..., 1].astype(object) << 21)
-        | idx[..., 2].astype(object)
-    )
-    bits = _value_bits(values)
-    total = 0
-    for key, b in zip(keys.ravel(), bits.ravel()):
-        total = (total + _mix(int(key)) * (int(b) + 1)) % MOD
-    return total, keys.size
+    keys = _triple_keys(i, j, k)
+    return _raw_total(keys, values), keys.size

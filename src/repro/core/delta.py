@@ -44,8 +44,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.compat import shard_map
-
 from repro.core.metric_spec import CZEKANOWSKI, MetricSpec
 from repro.core.plan2 import TwoWayPlan
 from repro.core.tile_executor import TileExecutor
@@ -252,13 +250,13 @@ def twoway_delta(
     out_dtype = jnp.dtype(cfg.out_dtype)
     fn = _cached_jit(
         ("delta", mesh, cfg, metric.name, str(out_dtype), planes),
-        lambda: shard_map(
+        lambda: jax.shard_map(
             partial(_twoway_delta_program, cfg=cfg, out_dtype=out_dtype,
                     metric=metric, planes=planes),
             mesh=mesh,
             in_specs=in_specs,
             out_specs=(P(("pv", "pr"), None), P(("pv", "pr"), None, None)),
-            check=False,
+            check_vma=False,
         ),
     )
     with obs.span("delta-border") as sp:

@@ -49,8 +49,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import shard_map
-
 from repro.core import checksum as ck
 from repro.core.metric_spec import (
     CZEKANOWSKI,
@@ -545,13 +543,13 @@ def threeway_distributed(
     plan = ThreeWayPlan(cfg.n_pv, cfg.n_pr, cfg.n_st)
     out_dtype = jnp.dtype(cfg.out_dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_threeway_program, cfg=cfg, plan=plan, stage=stage,
                 out_dtype=out_dtype, metric=metric),
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P("pv", "pr", None, None, None, None),
-        check=False,
+        check_vma=False,
     )
     jfn = jax.jit(fn, static_argnames=())
     with obs.span("ring-step") as sp:
@@ -584,13 +582,13 @@ def threeway_batched(
     plan = ThreeWayPlan(cfg.n_pv, cfg.n_pr, cfg.n_st)
     out_dtype = jnp.dtype(cfg.out_dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_threeway_program, cfg=cfg, plan=plan, stage=stage,
                 out_dtype=out_dtype, groups=groups),
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P("pv", "pr", None, None, None, None, None),
-        check=False,
+        check_vma=False,
     )
     jfn = jax.jit(fn)
     with obs.span("ring-step") as sp:

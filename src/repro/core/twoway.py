@@ -35,8 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import shard_map
-
 from repro.core import checksum as ck
 from repro.obs import trace as obs
 from repro.core.metric_spec import (
@@ -546,13 +544,13 @@ def twoway_distributed(
 
     fn = _cached_jit(
         ("twoway", mesh, cfg, plan, metric.name, str(out_dtype), planes),
-        lambda: shard_map(
+        lambda: jax.shard_map(
             partial(_twoway_program, cfg=cfg, plan=plan, out_dtype=out_dtype,
                     metric=metric, planes=planes),
             mesh=mesh,
             in_specs=in_specs,
             out_specs=P("pv", "pr", None, None, None),
-            check=False,
+            check_vma=False,
         ),
     )
     with obs.span("ring-step") as sp:
@@ -695,13 +693,13 @@ def twoway_batched(
     plan = TwoWayPlan(cfg.n_pv, cfg.n_pr)
     out_dtype = jnp.dtype(cfg.out_dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_twoway_batched_program, cfg=cfg, plan=plan,
                 out_dtype=out_dtype, groups=groups, planes=planes),
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P("pv", "pr", None, None, None, None),
-        check=False,
+        check_vma=False,
     )
     jfn = jax.jit(fn)
     with obs.span("ring-step") as sp:

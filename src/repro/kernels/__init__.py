@@ -1,3 +1,9 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the similarity engines (packages ``mgemm``,
+``mgemm_levels``, ``popgemm``, ``czek3``)."""
+import jax
+
+
+def interpret_mode() -> bool:
+    """Default ``interpret`` flag of every kernel wrapper: Mosaic-compiled on
+    a TPU backend, the Pallas interpreter on any other (the CPU tests)."""
+    return jax.default_backend() != "tpu"

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mgemm.kernel import K_CHUNK, _accumulate, select_column
 # shared with the 2-way plane kernels so the bit layout and the MXU
 # accumulation (dot shape, preferred_element_type) can never drift
 from repro.kernels.mgemm_levels.kernel import DEFAULT_BKB, _plane_matmuls
@@ -47,32 +48,27 @@ from repro.kernels.mgemm_levels.kernel import DEFAULT_BKB, _plane_matmuls
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 512
-K_CHUNK = 8
+
+
+def _xj_contract(xo, right_ref, xo_ref, combine, k_chunk):
+    """sum_q combine(xo[q, i], right[q, k]) for one K-tile: the fused X_j
+    tile is stored vector-major in VMEM scratch so the 2-way contraction
+    (``mgemm._accumulate``) reads it as its A operand."""
+    xo_ref[...] = xo.T
+    return _accumulate(xo_ref, right_ref, combine, k_chunk)
 
 
 def _threeway_kernel(
-    own_ref, x_ref, right_ref, o_ref, acc_ref, *, n_k_steps, k_chunk, combine
+    own_ref, x_ref, right_ref, o_ref, acc_ref, xo_ref,
+    *, n_k_steps, k_chunk, combine,
 ):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    own = own_ref[...]  # (bk, bm)  field-major
-    x = x_ref[...]  # (bk, 1)
-    right = right_ref[...]  # (bk, bn)
-    bk, bm = own.shape
-    bn = right.shape[1]
-    xo = combine(own, x)  # fused X_j tile — never written to HBM
-
-    def body(t, acc):
-        a_sub = jax.lax.dynamic_slice(xo, (t * k_chunk, 0), (k_chunk, bm))
-        b_sub = jax.lax.dynamic_slice(right, (t * k_chunk, 0), (k_chunk, bn))
-        m = combine(a_sub[:, :, None], b_sub[:, None, :]).astype(jnp.float32)
-        return acc + m.sum(axis=0)
-
-    acc_ref[...] += jax.lax.fori_loop(
-        0, bk // k_chunk, body, jnp.zeros((bm, bn), jnp.float32)
-    )
+    # fused X_j tile (bk, bm) — never written to HBM
+    xo = combine(own_ref[...], x_ref[...])
+    acc_ref[...] += _xj_contract(xo, right_ref, xo_ref, combine, k_chunk)
 
     @pl.when(pl.program_id(2) == n_k_steps - 1)
     def _flush():
@@ -130,14 +126,18 @@ def threeway_step_pallas(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((bm, bn), jnp.float32),
+            pltpu.VMEM((bm, bk), own.dtype),
+        ],
         interpret=interpret,
     )(own, x, right)
     return out[:m, :n]
 
 
 def _threeway_batch_kernel(
-    own_ref, x_ref, right_ref, o_ref, acc_ref, *, n_k_steps, k_chunk, combine
+    own_ref, x_ref, right_ref, o_ref, acc_ref, xo_ref,
+    *, n_k_steps, k_chunk, combine,
 ):
     """Batched variant: grid axis 0 walks the pipeline columns, so a whole
     (n_fp, L) slice runs as ONE kernel launch (the accumulator still lives
@@ -146,22 +146,9 @@ def _threeway_batch_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    own = own_ref[...]  # (bk, bm)
-    x = x_ref[...]  # (bk, 1) — this grid step's pipeline column
-    right = right_ref[...]  # (bk, bn)
-    bk, bm = own.shape
-    bn = right.shape[1]
-    xo = combine(own, x)  # fused X_j tile — never written to HBM
-
-    def body(t, acc):
-        a_sub = jax.lax.dynamic_slice(xo, (t * k_chunk, 0), (k_chunk, bm))
-        b_sub = jax.lax.dynamic_slice(right, (t * k_chunk, 0), (k_chunk, bn))
-        m = combine(a_sub[:, :, None], b_sub[:, None, :]).astype(jnp.float32)
-        return acc + m.sum(axis=0)
-
-    acc_ref[...] += jax.lax.fori_loop(
-        0, bk // k_chunk, body, jnp.zeros((bm, bn), jnp.float32)
-    )
+    x = select_column(x_ref[...], pl.program_id(0))  # (bk, 1)
+    xo = combine(own_ref[...], x)  # fused X_j tile — never written to HBM
+    acc_ref[...] += _xj_contract(xo, right_ref, xo_ref, combine, k_chunk)
 
     @pl.when(pl.program_id(3) == n_k_steps - 1)
     def _flush():
@@ -214,12 +201,15 @@ def threeway_batch_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((bk, bm), lambda l, i, j, t: (t, i)),
-            pl.BlockSpec((bk, 1), lambda l, i, j, t: (t, l)),
+            pl.BlockSpec((bk, L), lambda l, i, j, t: (t, 0)),
             pl.BlockSpec((bk, bn), lambda l, i, j, t: (t, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda l, i, j, t: (l, i, j)),
         out_shape=jax.ShapeDtypeStruct((L, M, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((bm, bn), jnp.float32),
+            pltpu.VMEM((bm, bk), own.dtype),
+        ],
         interpret=interpret,
     )(own, X, right)
     return out[:, :m, :n]
@@ -242,8 +232,10 @@ def _threeway_levels_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # packed AND == plane of min(own, x); x (levels, bkb, 1) broadcasts
-    xo = own_ref[...] & x_ref[...]
+    # packed AND == plane of min(own, x); this step's column
+    # (levels, bkb, 1) broadcasts over own's vectors
+    x = select_column(x_ref[...].astype(jnp.int32), pl.program_id(0))
+    xo = own_ref[...].astype(jnp.int32) & x
     acc_ref[...] += _plane_matmuls(xo, right_ref[...], levels)
 
     @pl.when(pl.program_id(3) == n_k_steps - 1)
@@ -295,7 +287,7 @@ def threeway_batch_levels_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((levels, bkb, bm), lambda l, i, j, t: (0, t, i)),
-            pl.BlockSpec((levels, bkb, 1), lambda l, i, j, t: (0, t, l)),
+            pl.BlockSpec((levels, bkb, L), lambda l, i, j, t: (0, t, 0)),
             pl.BlockSpec((levels, bkb, bn), lambda l, i, j, t: (0, t, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda l, i, j, t: (l, i, j)),

@@ -9,8 +9,9 @@ campaign path those planes are byte-range views of the ring payload.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
+
+from repro.kernels import interpret_mode
 
 from .kernel import (
     threeway_batch_levels_pallas,
@@ -19,17 +20,13 @@ from .kernel import (
 )
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def threeway_step(own, x, right, *, combine, **kw):
     """Metric-generic fused 3-way pipeline step (X_j never touches HBM).
 
     own (n_f, m), x (n_f,) single pipeline column, right (n_f, n) ->
     (m, n).  Single-column form kept for benchmarks/oracles; the executor
     runs the batched variants below."""
-    kw.setdefault("interpret", not _on_tpu())
+    kw.setdefault("interpret", interpret_mode())
     return threeway_step_pallas(own, x, right, combine=combine, **kw)
 
 
@@ -38,7 +35,7 @@ def threeway_batch(own, X, right, *, combine, **kw):
 
     own (n_f, m), X (n_f, L), right (n_f, n) -> (L, m, n) value-operand
     form (``path3 == "fused-vpu"``)."""
-    kw.setdefault("interpret", not _on_tpu())
+    kw.setdefault("interpret", interpret_mode())
     return threeway_batch_pallas(own, X, right, combine=combine, **kw)
 
 
@@ -49,7 +46,7 @@ def threeway_batch_levels(Pown, PX, Pright, **kw):
     (L, m, n).  The X_j plane is a packed AND in VMEM (one VPU op per 8
     fields), the contraction runs on the MXU; operands arrive pre-encoded
     (ring payload or ``encode_bitplanes``), never re-encoded here."""
-    kw.setdefault("interpret", not _on_tpu())
+    kw.setdefault("interpret", interpret_mode())
     return threeway_batch_levels_pallas(Pown, PX, Pright, **kw)
 
 

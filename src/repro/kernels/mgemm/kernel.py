@@ -4,7 +4,7 @@ the generated fused-epilogue metric kernels behind the ``TileExecutor``.
 TPU adaptation of the paper's modified-MAGMA GEMM.  The MXU cannot evaluate
 ``min`` inside its systolic array, so the contraction runs on the VPU:
 HBM -> VMEM tiles via BlockSpec, fp32 accumulation in a VMEM scratch
-accumulator, K-chunked broadcast-combine + reduce inside the block.
+accumulator, one (bm, 1) x (1, bn) broadcast-combine per field.
 
 Grid: (M/bm, N/bn, K/bk), K innermost so the accumulator tile stays resident
 in VMEM across the contraction (standard Pallas matmul pattern).
@@ -13,8 +13,9 @@ Default tile (bm, bn, bk) = (128, 128, 512):
   VMEM working set = A tile 128*512*4 B + B tile 512*128*4 B + acc 128*128*4 B
                    = 256 KiB + 256 KiB + 64 KiB ≈ 0.6 MiB  « 16 MiB VMEM,
 leaving room for double buffering of the input streams.  The inner k-chunk
-(8) bounds the broadcast intermediate to 128*8*128*4 = 512 KiB of VREG/VMEM
-traffic, aligned to the (8, 128) VPU vector register shape.
+(128 fields) is one lane-aligned slice of the A tile: the fori_loop walks
+the chunks, the fields of a chunk are unrolled, and no intermediate is
+larger than the (bm, bn) accumulator.
 
 Fused metric kernels (paper §3.1 epilogue fusion + §5 symmetry)
 ---------------------------------------------------------------
@@ -46,7 +47,7 @@ from repro.core.metric_spec import czek_assemble_tile
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 512
-K_CHUNK = 8
+K_CHUNK = 128
 
 __all__ = [
     "mgemm_pallas",
@@ -58,19 +59,34 @@ __all__ = [
 ]
 
 
-def _accumulate(a, b, combine, k_chunk):
-    """One (bm, bk) x (bk, bn) combine-sum contraction in fp32."""
-    bm, bk = a.shape
-    bn = b.shape[1]
+def _accumulate(a_ref, b_ref, combine, k_chunk):
+    """One (bm, bk) x (bk, bn) combine-sum contraction in fp32.
 
-    def body(t, acc):
-        a_sub = jax.lax.dynamic_slice(a, (0, t * k_chunk), (bm, k_chunk))
-        b_sub = jax.lax.dynamic_slice(b, (t * k_chunk, 0), (k_chunk, bn))
-        m = combine(a_sub[:, :, None], b_sub[None, :, :]).astype(jnp.float32)
-        return acc + m.sum(axis=1)
+    Walks ``k_chunk``-field slices of the refs (Mosaic lowers slices of
+    refs, not ``dynamic_slice`` of loaded values); inside a slice each
+    field is one (bm, 1) x (1, bn) broadcast-combine, statically unrolled.
+    On the TPU a slice start on A's lane axis must be a multiple of 128,
+    hence ``K_CHUNK``; a tile that ``k_chunk`` does not divide runs as one
+    slice."""
+    bm, bk = a_ref.shape
+    bn = b_ref.shape[1]
+    if bk % k_chunk:
+        k_chunk = bk
 
+    def chunk(off, acc):
+        a = a_ref[:, pl.ds(off, k_chunk)]
+        b = b_ref[pl.ds(off, k_chunk), :]
+        for q in range(k_chunk):
+            acc = acc + combine(a[:, q:q + 1], b[q:q + 1, :]).astype(jnp.float32)
+        return acc
+
+    acc = jnp.zeros((bm, bn), jnp.float32)
+    n_chunks = bk // k_chunk
+    if n_chunks == 1:
+        return chunk(0, acc)
     return jax.lax.fori_loop(
-        0, bk // k_chunk, body, jnp.zeros((bm, bn), jnp.float32)
+        0, n_chunks,
+        lambda c, acc: chunk(pl.multiple_of(c * k_chunk, k_chunk), acc), acc,
     )
 
 
@@ -79,7 +95,7 @@ def _mgemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k_steps: int, k_chunk: int)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += _accumulate(a_ref[...], b_ref[...], jnp.minimum, k_chunk)
+    acc_ref[...] += _accumulate(a_ref, b_ref, jnp.minimum, k_chunk)
 
     @pl.when(pl.program_id(2) == n_k_steps - 1)
     def _flush():
@@ -99,7 +115,7 @@ def _fused2_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += _accumulate(a_ref[...], b_ref[...], combine, k_chunk)
+    acc_ref[...] += _accumulate(a_ref, b_ref, combine, k_chunk)
 
     @pl.when(pl.program_id(2) == n_k_steps - 1)
     def _flush():
@@ -109,28 +125,25 @@ def _fused2_kernel(
 
 
 def _fused2_tri_kernel(
-    idx_ref, a_ref, b_ref, sa_ref, sb_ref, o_ref, acc_ref,
-    *, n_k_steps, k_chunk, combine, epilogue,
+    a_ref, b_ref, sa_ref, sb_ref, o_ref, acc_ref,
+    *, n_k_steps, k_chunk, combine, epilogue, T,
 ):
     """Triangular-schedule fused kernel for diagonal blocks (paper §5).
 
-    Grid axis 0 walks the packed tile list (only ``tj >= ti``); ``idx_ref``
-    carries this tile's (ti, tj) so the flush can zero the redundant
-    lower-and-diagonal entries of on-diagonal tiles in place."""
+    Grid axis 0 walks the packed tile list (only ``tj >= ti``); the flush
+    zeroes the redundant lower-and-diagonal entries of on-diagonal tiles
+    in place."""
     @pl.when(pl.program_id(1) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += _accumulate(a_ref[...], b_ref[...], combine, k_chunk)
+    acc_ref[...] += _accumulate(a_ref, b_ref, combine, k_chunk)
+    p = pl.program_id(0)
 
     @pl.when(pl.program_id(1) == n_k_steps - 1)
     def _flush():
         vals = epilogue(acc_ref[...], sa_ref[...], sb_ref[...])
-        on_diag = idx_ref[0, 0] == idx_ref[0, 1]
-        li = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 0)
-        lj = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
-        keep = jnp.logical_or(jnp.logical_not(on_diag), li < lj)
-        o_ref[0] = jnp.where(keep, vals, 0.0).astype(o_ref.dtype)
+        store_tri_tile(o_ref, vals, p, T)
 
 
 def _tri_decode(p, T: int):
@@ -146,6 +159,30 @@ def _tri_decode(p, T: int):
     r = jnp.where(r * (r + 1) // 2 > q, r - 1, r)
     o = q - r * (r + 1) // 2
     return T - 1 - r, T - 1 - o
+
+
+def store_tri_tile(o_ref, vals, p, T: int):
+    """Flush packed triangular tile ``p`` (its grid position, read outside
+    any ``pl.when``, where interpret mode cannot lower ``program_id``).
+    ``p`` decodes to the tile's (ti, tj) in the kernel, as a tile-index
+    input block would break the TPU's (8, 128) block rule; an on-diagonal
+    tile keeps only its strict upper triangle."""
+    ti, tj = _tri_decode(p, T)
+    li = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 0)
+    lj = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
+    keep = jnp.logical_or(ti != tj, li < lj)
+    o_ref[0] = jnp.where(keep, vals, 0.0).astype(o_ref.dtype)
+
+
+def select_column(tile, l):
+    """Column ``l`` (a grid index) of a (..., L) tile, as (..., 1).
+
+    The 3-way kernels read all L pipeline columns in one block, because the
+    TPU cannot DMA a width-1 block of a last axis narrower than 128, and
+    pick this step's column with a lane mask: an exact sum of one value
+    and zeros."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, tile.shape, tile.ndim - 1)
+    return jnp.sum(jnp.where(lane == l, tile, 0), axis=-1, keepdims=True)
 
 
 def tri_tile_coords(T: int) -> tuple[np.ndarray, np.ndarray]:
@@ -315,8 +352,6 @@ def metric2_tri_pallas(
     T = M // bt
     P = T * (T + 1) // 2
     n_k_steps = K // bk
-    ti, tj = tri_tile_coords(T)
-    idx = jnp.asarray(np.stack([ti, tj], axis=1))  # (P, 2) static schedule
 
     def a_map(p, t):
         return (_tri_decode(p, T)[0], t)
@@ -333,11 +368,10 @@ def metric2_tri_pallas(
     out = pl.pallas_call(
         functools.partial(
             _fused2_tri_kernel, n_k_steps=n_k_steps, k_chunk=k_chunk,
-            combine=combine, epilogue=epilogue,
+            combine=combine, epilogue=epilogue, T=T,
         ),
         grid=(P, n_k_steps),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda p, t: (p, 0)),
             pl.BlockSpec((bt, bk), a_map),
             pl.BlockSpec((bk, bt), b_map),
             pl.BlockSpec((bt, 1), sa_map),
@@ -347,7 +381,7 @@ def metric2_tri_pallas(
         out_shape=jax.ShapeDtypeStruct((P, bt, bt), out_dtype),
         scratch_shapes=[pltpu.VMEM((bt, bt), jnp.float32)],
         interpret=interpret,
-    )(idx, A, B, sa, sb)
+    )(A, B, sa, sb)
     return out
 
 

@@ -25,11 +25,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.mgemm.kernel import _tri_decode, tri_tile_coords
+from repro.kernels.mgemm.kernel import _tri_decode, store_tri_tile
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -163,8 +162,8 @@ def _levels_fused_kernel(
 
 
 def _levels_fused_tri_kernel(
-    idx_ref, pa_ref, pb_ref, sa_ref, sb_ref, o_ref, acc_ref,
-    *, n_k_steps: int, levels: int, epilogue,
+    pa_ref, pb_ref, sa_ref, sb_ref, o_ref, acc_ref,
+    *, n_k_steps: int, levels: int, epilogue, T: int,
 ):
     """Triangular-schedule plane kernel for diagonal blocks (paper §5):
     grid axis 0 walks only the ``tj >= ti`` tiles; on-diagonal tiles are
@@ -174,6 +173,7 @@ def _levels_fused_tri_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += _plane_matmuls(pa_ref[...], pb_ref[...], levels)
+    p = pl.program_id(0)
 
     @pl.when(pl.program_id(1) == n_k_steps - 1)
     def _flush():
@@ -181,11 +181,7 @@ def _levels_fused_tri_kernel(
         vals = acc if epilogue is None else epilogue(
             acc, sa_ref[...], sb_ref[...]
         )
-        on_diag = idx_ref[0, 0] == idx_ref[0, 1]
-        li = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 0)
-        lj = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
-        keep = jnp.logical_or(jnp.logical_not(on_diag), li < lj)
-        o_ref[0] = jnp.where(keep, vals, 0.0).astype(o_ref.dtype)
+        store_tri_tile(o_ref, vals, p, T)
 
 
 def _pad_planes(P, last_pad: int, kb_pad: int):
@@ -287,8 +283,6 @@ def metric2_levels_tri_pallas(
     T = M // bt
     nP = T * (T + 1) // 2
     n_k_steps = KB // bkb
-    ti, tj = tri_tile_coords(T)
-    idx = jnp.asarray(np.stack([ti, tj], axis=1))  # (nP, 2) static schedule
 
     def a_map(p, t):
         return (0, t, _tri_decode(p, T)[0])
@@ -305,11 +299,10 @@ def metric2_levels_tri_pallas(
     out = pl.pallas_call(
         functools.partial(
             _levels_fused_tri_kernel, n_k_steps=n_k_steps, levels=levels,
-            epilogue=epilogue,
+            epilogue=epilogue, T=T,
         ),
         grid=(nP, n_k_steps),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda p, t: (p, 0)),
             pl.BlockSpec((levels, bkb, bt), a_map),
             pl.BlockSpec((levels, bkb, bt), b_map),
             pl.BlockSpec((bt, 1), sa_map),
@@ -319,5 +312,5 @@ def metric2_levels_tri_pallas(
         out_shape=jax.ShapeDtypeStruct((nP, bt, bt), out_dtype),
         scratch_shapes=[pltpu.VMEM((bt, bt), jnp.float32)],
         interpret=interpret,
-    )(idx, P, P, sa, sb)
+    )(P, P, sa, sb)
     return out

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.mgemm import register_impl
+from repro.kernels import interpret_mode
 
 from .kernel import (
     metric2_levels_pallas,
@@ -16,12 +17,8 @@ from .kernel import (
 from .planes import decode_bitplanes
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def mgemm_levels(A, B, *, levels: int = 2, **kw):
-    kw.setdefault("interpret", not _on_tpu())
+    kw.setdefault("interpret", interpret_mode())
     return mgemm_levels_pallas(A, B, levels=levels, **kw)
 
 
@@ -42,13 +39,13 @@ def mgemm_levels_xla(A, B, *, levels: int = 2, out_dtype=jnp.float32):
 
 def metric2_levels(Pa, Pb, sa, sb, *, epilogue, **kw):
     """Fused metric kernel on pre-encoded packed planes (rectangular grid)."""
-    kw.setdefault("interpret", not _on_tpu())
+    kw.setdefault("interpret", interpret_mode())
     return metric2_levels_pallas(Pa, Pb, sa, sb, epilogue=epilogue, **kw)
 
 
 def metric2_levels_tri(P, s, *, epilogue, **kw):
     """Fused diagonal-block plane kernel (triangular tile schedule)."""
-    kw.setdefault("interpret", not _on_tpu())
+    kw.setdefault("interpret", interpret_mode())
     return metric2_levels_tri_pallas(P, s, epilogue=epilogue, **kw)
 
 
@@ -56,7 +53,7 @@ def mgemm_levels_planes(Pa, Pb, **kw):
     """Plane-contraction-only MXU kernel: the unfused numerator when the
     reduction is split over ranks (``n_pf > 1``) and the epilogue must wait
     for the psum."""
-    kw.setdefault("interpret", not _on_tpu())
+    kw.setdefault("interpret", interpret_mode())
     za = jnp.zeros((Pa.shape[2],), jnp.float32)
     zb = jnp.zeros((Pb.shape[2],), jnp.float32)
     return metric2_levels_pallas(Pa, Pb, za, zb, epilogue=None, **kw)

@@ -12,7 +12,7 @@ for biobank-scale binary genotype arithmetic):
 
 Where the levels path inflates each byte tile 8x into bf16 indicators
 before contracting, these kernels AND the byte tiles directly, group 4
-consecutive bytes into one int32 word per lane, and accumulate
+bytes of a K-tile into one int32 word per lane, and accumulate
 ``lax.population_count`` of the AND outer product — no unpack shuffle and
 1/8 the VMEM indicator footprint on the hottest binary-workload loop.
 
@@ -27,10 +27,12 @@ fp32, so campaign checksums stay bit-identical to ``impl="xla"`` across
 every decomposition, chunking, and path — popcount partials also ADD
 exactly, which is what keeps the streamed/merge paths on this kernel.
 
-Mosaic note: ``lax.population_count`` is exercised interpret-mode in CI;
-its real-TPU Mosaic lowering still needs a v5e check (ROADMAP "Real-TPU
-validation") — the SWAR shift/mask/add formulation is the drop-in
-fallback if the op is unsupported there.
+Mosaic note: ``lax.population_count`` on int32 words lowers for the v5e
+(``tests/test_tpu_compile.py`` compiles every kernel here for a described
+chip), and on one v5e chip the popcount campaign's checksum equals
+``impl="xla"``'s, so no SWAR fallback is needed.  What Mosaic refused was
+the loop around it: ``dynamic_slice`` of loaded values, hence one
+unrolled broadcast AND per word row.
 """
 from __future__ import annotations
 
@@ -38,76 +40,65 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.mgemm.kernel import _tri_decode, tri_tile_coords
+from repro.kernels.mgemm.kernel import _tri_decode, select_column, store_tri_tile
 from repro.kernels.mgemm_levels.kernel import _pad_planes, _pad_stat
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
-# byte tile of the contraction axis; wrappers round it up so every K-tile
-# packs into whole (4-byte) words and whole popcount chunks
+# byte tile of the contraction axis; wrappers round it up to whole
+# WORD_ALIGN-byte units so the four quarter slices of ``_pack_words`` start
+# on 8-row (sublane) boundaries
 DEFAULT_BKB = 64
-# int32 words (= 256 fields) popcounted per fori_loop step — bounds the
-# (k_chunk, bm, bn) AND/popcount intermediate like czek3's K_CHUNK; 8
-# words is 2 MiB of int32 intermediate at the default 256x256 tile
-# (VMEM-safe) and measurably ahead of 4 on the loop-overhead side
-K_CHUNK = 8
+WORD_ALIGN = 32
 DEFAULT_BM3 = 128
 DEFAULT_BN3 = 128
 
 
 def _pack_words(tile):
-    """(bkb, w) packed uint8 -> (bkb//4, w) int32 words, little-endian.
+    """(bkb, w) packed uint8 -> (bkb//4, w) int32 words.
 
-    AND distributes over the 4-byte grouping, so popcount(AND of words) ==
-    popcount(AND of bytes); callers align ``bkb`` to whole words.  The
-    int32 may wrap negative when byte 3 has its top bit set — the bit
-    pattern (what ``population_count`` sees) is still exact."""
-    kb, w = tile.shape
-    b = tile.astype(jnp.int32).reshape(kb // 4, 4, w)
-    return b[:, 0] | (b[:, 1] << 8) | (b[:, 2] << 16) | (b[:, 3] << 24)
+    Word r holds bytes r, r + q, r + 2q and r + 3q (q = bkb // 4).  AND and
+    popcount act bit by bit, so any byte grouping both operands share gives
+    the same count; quarter slices need no reshape of the sublane axis.
+    The int32 may wrap negative —
+    the bit pattern (what ``population_count`` sees) is still exact."""
+    q = tile.shape[0] // 4
+    b = tile.astype(jnp.int32)
+    return b[:q] | (b[q:2 * q] << 8) | (b[2 * q:3 * q] << 16) | (b[3 * q:] << 24)
 
 
-def _pop_contract(pa, pb, k_chunk: int):
+def _pop_contract(pa, pb):
     """out[i, j] = sum_q popcount(pa[q, i] & pb[q, j]) for one K-tile.
 
-    pa (bkb, bm), pb (bkb, bn) packed uint8 -> (bm, bn) fp32.  The AND
-    outer product is popcounted ``k_chunk`` words at a time to bound the
-    (k_chunk, bm, bn) intermediate."""
-    wa = _pack_words(pa)
-    wb = _pack_words(pb)
-    nw, bm = wa.shape
-    bn = wb.shape[1]
-
-    def body(t, acc):
-        a_sub = jax.lax.dynamic_slice(wa, (t * k_chunk, 0), (k_chunk, bm))
-        b_sub = jax.lax.dynamic_slice(wb, (t * k_chunk, 0), (k_chunk, bn))
-        pc = jax.lax.population_count(a_sub[:, :, None] & b_sub[:, None, :])
-        return acc + pc.sum(axis=0).astype(jnp.float32)
-
-    return jax.lax.fori_loop(
-        0, nw // k_chunk, body, jnp.zeros((bm, bn), jnp.float32)
-    )
+    pa (bkb, bm), pb (bkb, bn) packed bytes -> (bm, bn) fp32.  Each int32
+    word row is one (bm, 1) & (1, bn) broadcast AND + popcount, statically
+    unrolled (bkb // 4 words per tile), counted in int32 and converted
+    once."""
+    wa = _pack_words(pa).T  # (bm, nw): A's vectors on sublanes
+    wb = _pack_words(pb)  # (nw, bn)
+    acc = jnp.zeros((wa.shape[0], wb.shape[1]), jnp.int32)
+    for r in range(wb.shape[0]):
+        acc += jax.lax.population_count(wa[:, r:r + 1] & wb[r:r + 1, :])
+    return acc.astype(jnp.float32)
 
 
-def _word_align(bkb: int, k_chunk: int) -> int:
-    """Round a byte-tile size up to whole popcount chunks of int32 words."""
-    unit = 4 * k_chunk
-    return -(-bkb // unit) * unit
+def _word_align(bkb: int) -> int:
+    """Round a byte-tile size up to whole ``WORD_ALIGN`` units."""
+    return -(-bkb // WORD_ALIGN) * WORD_ALIGN
 
 
 def _pop_fused_kernel(
     pa_ref, pb_ref, sa_ref, sb_ref, o_ref, acc_ref,
-    *, n_k_steps: int, k_chunk: int, epilogue,
+    *, n_k_steps: int, epilogue,
 ):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += _pop_contract(pa_ref[0], pb_ref[0], k_chunk)
+    acc_ref[...] += _pop_contract(pa_ref[0], pb_ref[0])
 
     @pl.when(pl.program_id(2) == n_k_steps - 1)
     def _flush():
@@ -119,8 +110,8 @@ def _pop_fused_kernel(
 
 
 def _pop_fused_tri_kernel(
-    idx_ref, pa_ref, pb_ref, sa_ref, sb_ref, o_ref, acc_ref,
-    *, n_k_steps: int, k_chunk: int, epilogue,
+    pa_ref, pb_ref, sa_ref, sb_ref, o_ref, acc_ref,
+    *, n_k_steps: int, epilogue, T: int,
 ):
     """Triangular-schedule popcount kernel for diagonal blocks (paper §5):
     grid axis 0 walks only the ``tj >= ti`` tiles; on-diagonal tiles are
@@ -129,7 +120,8 @@ def _pop_fused_tri_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += _pop_contract(pa_ref[0], pb_ref[0], k_chunk)
+    acc_ref[...] += _pop_contract(pa_ref[0], pb_ref[0])
+    p = pl.program_id(0)
 
     @pl.when(pl.program_id(1) == n_k_steps - 1)
     def _flush():
@@ -137,17 +129,13 @@ def _pop_fused_tri_kernel(
         vals = acc if epilogue is None else epilogue(
             acc, sa_ref[...], sb_ref[...]
         )
-        on_diag = idx_ref[0, 0] == idx_ref[0, 1]
-        li = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 0)
-        lj = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
-        keep = jnp.logical_or(jnp.logical_not(on_diag), li < lj)
-        o_ref[0] = jnp.where(keep, vals, 0.0).astype(o_ref.dtype)
+        store_tri_tile(o_ref, vals, p, T)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "epilogue", "bm", "bn", "bkb", "k_chunk", "interpret", "out_dtype"
+        "epilogue", "bm", "bn", "bkb", "interpret", "out_dtype"
     ),
 )
 def metric2_pop_pallas(
@@ -160,7 +148,6 @@ def metric2_pop_pallas(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bkb: int = DEFAULT_BKB,
-    k_chunk: int = K_CHUNK,
     interpret: bool = False,
     out_dtype=jnp.float32,
 ):
@@ -175,7 +162,7 @@ def metric2_pop_pallas(
     levels, kb, m = Pa.shape
     n = Pb.shape[2]
     assert levels == 1 and Pb.shape[:2] == (1, kb), (Pa.shape, Pb.shape)
-    bkb = _word_align(bkb, k_chunk)
+    bkb = _word_align(bkb)
     mp, np_, kbp = (-m) % bm, (-n) % bn, (-kb) % bkb
     Pa = _pad_planes(Pa, mp, kbp)
     Pb = _pad_planes(Pb, np_, kbp)
@@ -186,7 +173,7 @@ def metric2_pop_pallas(
     grid = (M // bm, N // bn, n_k_steps)
     out = pl.pallas_call(
         functools.partial(
-            _pop_fused_kernel, n_k_steps=n_k_steps, k_chunk=k_chunk,
+            _pop_fused_kernel, n_k_steps=n_k_steps,
             epilogue=epilogue,
         ),
         grid=grid,
@@ -207,7 +194,7 @@ def metric2_pop_pallas(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "epilogue", "bt", "bkb", "k_chunk", "interpret", "out_dtype"
+        "epilogue", "bt", "bkb", "interpret", "out_dtype"
     ),
 )
 def metric2_pop_tri_pallas(
@@ -217,7 +204,6 @@ def metric2_pop_tri_pallas(
     epilogue,
     bt: int = DEFAULT_BM,
     bkb: int = DEFAULT_BKB,
-    k_chunk: int = K_CHUNK,
     interpret: bool = False,
     out_dtype=jnp.float32,
 ):
@@ -229,7 +215,7 @@ def metric2_pop_tri_pallas(
     in ``tri_tile_coords`` order, like ``metric2_levels_tri_pallas``."""
     levels, kb, m = P.shape
     assert levels == 1, P.shape
-    bkb = _word_align(bkb, k_chunk)
+    bkb = _word_align(bkb)
     mp, kbp = (-m) % bt, (-kb) % bkb
     P = _pad_planes(P, mp, kbp)
     sp = _pad_stat(s, mp)
@@ -238,8 +224,6 @@ def metric2_pop_tri_pallas(
     T = M // bt
     nP = T * (T + 1) // 2
     n_k_steps = KB // bkb
-    ti, tj = tri_tile_coords(T)
-    idx = jnp.asarray(np.stack([ti, tj], axis=1))  # (nP, 2) static schedule
 
     def a_map(p, t):
         return (0, t, _tri_decode(p, T)[0])
@@ -255,12 +239,11 @@ def metric2_pop_tri_pallas(
 
     out = pl.pallas_call(
         functools.partial(
-            _pop_fused_tri_kernel, n_k_steps=n_k_steps, k_chunk=k_chunk,
-            epilogue=epilogue,
+            _pop_fused_tri_kernel, n_k_steps=n_k_steps,
+            epilogue=epilogue, T=T,
         ),
         grid=(nP, n_k_steps),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda p, t: (p, 0)),
             pl.BlockSpec((1, bkb, bt), a_map),
             pl.BlockSpec((1, bkb, bt), b_map),
             pl.BlockSpec((bt, 1), sa_map),
@@ -270,7 +253,7 @@ def metric2_pop_tri_pallas(
         out_shape=jax.ShapeDtypeStruct((nP, bt, bt), out_dtype),
         scratch_shapes=[pltpu.VMEM((bt, bt), jnp.float32)],
         interpret=interpret,
-    )(idx, P, P, sa, sb)
+    )(P, P, sa, sb)
     return out
 
 
@@ -284,15 +267,16 @@ def metric2_pop_tri_pallas(
 
 
 def _threeway_pop_kernel(
-    own_ref, x_ref, right_ref, o_ref, acc_ref, *, n_k_steps, k_chunk
+    own_ref, x_ref, right_ref, o_ref, acc_ref, *, n_k_steps
 ):
     @pl.when(pl.program_id(3) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # packed AND == plane of min(own, x); x (1, bkb, 1) broadcasts
-    xo = own_ref[0] & x_ref[0]
-    acc_ref[...] += _pop_contract(xo, right_ref[0], k_chunk)
+    # packed AND == plane of min(own, x); this step's column (bkb, 1)
+    # broadcasts over own's vectors
+    x = select_column(x_ref[0].astype(jnp.int32), pl.program_id(0))
+    acc_ref[...] += _pop_contract(own_ref[0].astype(jnp.int32) & x, right_ref[0])
 
     @pl.when(pl.program_id(3) == n_k_steps - 1)
     def _flush():
@@ -301,7 +285,7 @@ def _threeway_pop_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bm", "bn", "bkb", "k_chunk", "interpret", "out_dtype"),
+    static_argnames=("bm", "bn", "bkb", "interpret", "out_dtype"),
 )
 def threeway_batch_pop_pallas(
     Pown,
@@ -311,7 +295,6 @@ def threeway_batch_pop_pallas(
     bm: int = DEFAULT_BM3,
     bn: int = DEFAULT_BN3,
     bkb: int = DEFAULT_BKB,
-    k_chunk: int = K_CHUNK,
     interpret: bool = False,
     out_dtype=jnp.float32,
 ):
@@ -327,7 +310,7 @@ def threeway_batch_pop_pallas(
     assert levels == 1, Pown.shape
     L = PX.shape[2]
     n = Pright.shape[2]
-    bkb = _word_align(bkb, k_chunk)
+    bkb = _word_align(bkb)
     mp, np_, kbp = (-m) % bm, (-n) % bn, (-kb) % bkb
     if mp or kbp:
         Pown = jnp.pad(Pown, ((0, 0), (0, kbp), (0, mp)))
@@ -340,12 +323,12 @@ def threeway_batch_pop_pallas(
     grid = (L, M // bm, N // bn, n_k_steps)
     out = pl.pallas_call(
         functools.partial(
-            _threeway_pop_kernel, n_k_steps=n_k_steps, k_chunk=k_chunk,
+            _threeway_pop_kernel, n_k_steps=n_k_steps,
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bkb, bm), lambda l, i, j, t: (0, t, i)),
-            pl.BlockSpec((1, bkb, 1), lambda l, i, j, t: (0, t, l)),
+            pl.BlockSpec((1, bkb, L), lambda l, i, j, t: (0, t, 0)),
             pl.BlockSpec((1, bkb, bn), lambda l, i, j, t: (0, t, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda l, i, j, t: (l, i, j)),

@@ -101,9 +101,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
 
     print(f"== {arch} x {shape} ({'2x16x16' if multi_pod else '16x16'}) ==")
     print(compiled.memory_analysis())
-    from repro.parallel.compat import cost_analysis_dict
-
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     print({k: ca[k] for k in ("flops", "bytes accessed") if k in ca})
 
     terms = analyze_compiled(compiled, n_dev, vpu_fraction=vpu_fraction)

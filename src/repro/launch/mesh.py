@@ -9,8 +9,7 @@ state (the dry-run must set XLA_FLAGS before any jax initialization).
 from __future__ import annotations
 
 import jax
-
-from repro.parallel.compat import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,4 +24,6 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {need} devices for the production mesh, have {len(devices)}"
             " (dry-run sets --xla_force_host_platform_device_count=512)"
         )
-    return make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(
+        shape, axes, (AxisType.Auto,) * len(axes), devices=devices
+    )

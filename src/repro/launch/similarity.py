@@ -12,6 +12,25 @@ elementwise comparisons/second (the paper's headline metric).
 import argparse
 import os
 import sys
+from pathlib import Path
+
+#: fixed cache location when JAX_COMPILATION_CACHE_DIR is unset: the path is
+#: part of the cache key, so it must not move between runs of a checkout
+CHECKOUT_CACHE_DIR = str(Path(os.path.abspath(__file__)).parents[3] / ".jax_cache")
+
+
+def init_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; call before the first
+    compile.  ``JAX_COMPILATION_CACHE_DIR``, when set, is used as given (JAX
+    reads it itself); otherwise the cache goes to ``<checkout>/.jax_cache``.
+    Returns the directory in use."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = CHECKOUT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _parse_metrics(metrics: str, metric: str) -> list:
@@ -202,6 +221,7 @@ def main(argv=None):
             f"--xla_force_host_platform_device_count={args.devices} "
             + os.environ.get("XLA_FLAGS", "")
         )
+    init_compile_cache()
     from repro.api import (
         InputSpec,
         SimilarityEngine,

@@ -223,7 +223,6 @@ def build_cell(arch: str, shape_name: str, mesh: Mesh, overrides=None):
 def build_comet_cell(arch: str, mesh: Mesh, multi_pod: bool, overrides=None):
     """Lowerable distributed similarity engine over the pod's devices."""
     from repro.configs.registry import get_config as _gc
-    from repro.parallel.compat import shard_map
     from repro.core.plan2 import TwoWayPlan
     from repro.core.plan3 import ThreeWayPlan
     from repro.core.threeway import _threeway_program
@@ -250,18 +249,18 @@ def build_comet_cell(arch: str, mesh: Mesh, multi_pod: bool, overrides=None):
     out_dtype = jnp.dtype(ccfg.out_dtype)
     if ccfg.way == 2:
         plan = TwoWayPlan(n_pv, n_pr)
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(_twoway_program, cfg=comet_cfg, plan=plan, out_dtype=out_dtype),
             mesh=cmesh, in_specs=P("pf", "pv"),
-            out_specs=P("pv", "pr", None, None, None), check=False,
+            out_specs=P("pv", "pr", None, None, None), check_vma=False,
         )
     else:
         plan = ThreeWayPlan(n_pv, n_pr, ccfg.n_st)
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(_threeway_program, cfg=comet_cfg, plan=plan, stage=0,
                     out_dtype=out_dtype),
             mesh=cmesh, in_specs=P("pf", "pv"),
-            out_specs=P("pv", "pr", None, None, None, None), check=False,
+            out_specs=P("pv", "pr", None, None, None, None), check_vma=False,
         )
     # cost_analysis statically counts EVERY round-robin cond branch; a rank
     # executes only its share at runtime.  work_fraction rescales the

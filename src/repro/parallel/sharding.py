@@ -23,8 +23,6 @@ from contextlib import contextmanager
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.parallel.compat import set_mesh
-
 _ACTIVE: list[Mesh] = []
 _DP_ONLY: list[bool] = []
 DATA_AXES = ("pod", "data")  # folded batch axes (pod may be absent)
@@ -69,7 +67,7 @@ def use_mesh(mesh: Mesh | None):
         return
     _ACTIVE.append(mesh)
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             yield
     finally:
         _ACTIVE.pop()

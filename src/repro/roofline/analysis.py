@@ -41,9 +41,7 @@ def analyze_compiled(compiled, n_devices: int, hw: Hardware = HW_V5E,
     """
     from repro.roofline.hlo import analyze_hlo
 
-    from repro.parallel.compat import cost_analysis_dict
-
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     text = compiled.as_text()
     hc = analyze_hlo(text, n_devices)
     # loop-aware HLO cost model (while bodies x trip count); XLA's own

@@ -39,7 +39,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.obs import trace as obs
-from repro.parallel.compat import shard_map
 
 from repro.core.metric_spec import (
     CZEKANOWSKI,
@@ -189,12 +188,12 @@ def stream_twoway(
         max_host_bytes=cfg.max_host_bytes,
     )
 
-    jfn = jax.jit(shard_map(
+    jfn = jax.jit(jax.shard_map(
         partial(_twoway_deferred_program, cfg=cfg, plan=plan, metric=metric),
         mesh=mesh,
         in_specs=P(None, "pf", "pv"),
         out_specs=(P("pv", "pr", None, None, None), P("pv", None)),
-        check=False,
+        check_vma=False,
     ))
 
     acc = np.zeros(
@@ -292,7 +291,7 @@ def stream_threeway(
     )
 
     out_dtype = jnp.dtype(cfg.out_dtype)
-    jfn = jax.jit(shard_map(
+    jfn = jax.jit(jax.shard_map(
         partial(_threeway_program, cfg=cfg, plan=plan, stage=stage,
                 out_dtype=out_dtype, metric=metric, deferred=True),
         mesh=mesh,
@@ -304,7 +303,7 @@ def stream_threeway(
             P("pv", "pr", None, None, None),  # left x right
             P("pv", None),  # stat partial
         ),
-        check=False,
+        check_vma=False,
     ))
 
     shape = (cfg.n_pv, cfg.n_pr, slots)
@@ -372,7 +371,7 @@ def stream_twoway_delta(
         max_host_bytes=cfg.max_host_bytes,
     )
 
-    jfn = jax.jit(shard_map(
+    jfn = jax.jit(jax.shard_map(
         partial(_twoway_delta_deferred_program, cfg=cfg, metric=metric),
         mesh=mesh,
         in_specs=(P(None, "pf", ("pv", "pr")), P(None, "pf", None)),
@@ -382,7 +381,7 @@ def stream_twoway_delta(
             P(("pv", "pr")),  # old stat partial
             P(("pv", "pr"), None),  # new stat partial (replicated)
         ),
-        check=False,
+        check_vma=False,
     ))
 
     rect_acc = np.zeros((n_op_total, m), np.float32)
@@ -488,14 +487,14 @@ def stream_twoway_batched(dataset, mesh, cfg: CometConfig, specs) -> tuple:
         max_host_bytes=cfg.max_host_bytes,
     )
 
-    jfn = jax.jit(shard_map(
+    jfn = jax.jit(jax.shard_map(
         partial(_twoway_deferred_batched_program, cfg=cfg, plan=plan,
                 groups=groups),
         mesh=mesh,
         in_specs=P(None, "pf", "pv"),
         out_specs=(P("pv", "pr", None, None, None, None),
                    P("pv", None, None)),
-        check=False,
+        check_vma=False,
     ))
 
     G = len(groups)
@@ -559,7 +558,7 @@ def stream_threeway_batched(
     )
 
     out_dtype = jnp.dtype(cfg.out_dtype)
-    jfn = jax.jit(shard_map(
+    jfn = jax.jit(jax.shard_map(
         partial(_threeway_program, cfg=cfg, plan=plan, stage=stage,
                 out_dtype=out_dtype, groups=groups, deferred=True),
         mesh=mesh,
@@ -571,7 +570,7 @@ def stream_threeway_batched(
             P("pv", "pr", None, None, None, None),  # left x right
             P("pv", None, None),  # per-family stat partials
         ),
-        check=False,
+        check_vma=False,
     ))
 
     G = len(groups)

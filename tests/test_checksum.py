@@ -1,5 +1,6 @@
 """Checksum contract tests (paper §5 validation machinery)."""
 import numpy as np
+import pytest
 
 from repro.core import checksum as ck
 
@@ -47,6 +48,36 @@ def test_combine_matches_monolithic():
     whole = ck.checksum_pairs(i, j, v)
     parts = [ck.raw_pairs(i[:20], j[:20], v[:20]), ck.raw_pairs(i[20:], j[20:], v[20:])]
     assert ck.combine(parts) == whole
+
+
+def _loop_raw(keys, bits):
+    """The checksum's definition, one Python integer per entry."""
+    total = 0
+    for k, b in zip(keys, bits):
+        total = (total + ck._mix(int(k)) * (int(b) + 1)) % ck.MOD
+    return total, len(keys)
+
+
+@pytest.mark.parametrize("way", [2, 3])
+@pytest.mark.parametrize("dtype", [np.float32, np.float16, np.float64])
+def test_vectorized_matches_per_entry_loop(monkeypatch, way, dtype):
+    monkeypatch.setattr(ck, "_BLOCK", 7)  # several partial-sum blocks
+    rng = np.random.default_rng(4)
+    n = 50
+    top = (1 << 31) if way == 2 else (1 << 21)
+    idx = rng.integers(0, top, size=(way, n))
+    idx[:, 0] = top - 1  # the widest key the packing allows
+    v = (rng.random(n) * 4).astype(dtype)
+    v[1] = np.finfo(dtype).max  # all value bits set but the sign
+    bits = v.view({4: np.uint32, 2: np.uint16, 8: np.uint64}[v.itemsize])
+    s = np.sort(idx, axis=0)
+    if way == 2:
+        keys = [(int(a) << 32) | int(b) for a, b in zip(*s)]
+        got = ck.raw_pairs(idx[1], idx[0], v)
+    else:
+        keys = [(int(a) << 42) | (int(b) << 21) | int(c) for a, b, c in zip(*s)]
+        got = ck.raw_triples(idx[2], idx[0], idx[1], v)
+    assert got == _loop_raw(keys, bits)
 
 
 def test_triples_order_and_canonicalization():

@@ -74,9 +74,9 @@ def test_elastic_restore_with_shardings(tmp_path):
     m = CheckpointManager(str(tmp_path), keep=1)
     tree = {"w": jnp.arange(16.0).reshape(4, 4), "b": jnp.ones(4)}
     m.save(1, tree, blocking=True)
-    from repro.parallel.compat import make_mesh
+    from jax.sharding import AxisType
 
-    mesh = make_mesh((1,), ("data",), devices=jax.devices()[:1])
+    mesh = jax.make_mesh((1,), ("data",), (AxisType.Auto,), devices=jax.devices()[:1])
     sh = {
         "w": NamedSharding(mesh, P("data", None)),
         "b": NamedSharding(mesh, P()),

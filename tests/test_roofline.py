@@ -38,9 +38,7 @@ def test_scan_multiplies_body_cost():
     cost, compiled = _cost(f, x)
     one = 2 * 128**3
     assert cost.flops == pytest.approx(10 * one, rel=0.05)
-    from repro.parallel.compat import cost_analysis_dict
-
-    assert float(cost_analysis_dict(compiled)["flops"]) == pytest.approx(one, rel=0.05)
+    assert float(compiled.cost_analysis()["flops"]) == pytest.approx(one, rel=0.05)
 
 
 def test_nested_scan_multiplies():
@@ -80,15 +78,15 @@ def test_collectives_in_loop_multiplied():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.compat import make_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.roofline.hlo import analyze_hlo
-        mesh = make_mesh((4,), ("x",))
+        mesh = jax.make_mesh((4,), ("x",), (AxisType.Auto,))
         def prog(v):
             def body(i, c):
                 return jax.lax.ppermute(c, "x", [(a, (a+1)%4) for a in range(4)])
             return jax.lax.fori_loop(0, 7, body, v)
-        f = shard_map(prog, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
-                      check=False)
+        f = jax.shard_map(prog, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+                          check_vma=False)
         x = jax.ShapeDtypeStruct((4, 100), jnp.float32)
         c = jax.jit(f).lower(x).compile()
         cost = analyze_hlo(c.as_text(), 4)
