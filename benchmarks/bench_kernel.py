@@ -385,7 +385,6 @@ def batched_sweep(shape=BATCHED_SHAPE, max_value=2):
             "phases": {
                 name: p["seconds"]
                 for name, p in sorted(tracer.phase_stats().items())
-                if name != "roofline"
             },
             "comparisons_per_s": result.meta["obs"]["comparisons_per_s"],
         }

@@ -26,6 +26,7 @@ from repro.api.result import SimilarityResult
 from repro.core.threeway import threeway_distributed
 from repro.core.twoway import twoway_distributed
 from repro.obs import trace as obs
+from repro.obs.metrics import count_jit_events, jit_counts
 from repro.parallel.mesh import COMET_AXES, make_comet_mesh
 
 __all__ = ["SimilarityEngine"]
@@ -47,43 +48,28 @@ def _campaign_comparisons(result) -> int:
     return int(result.num_results()) * int(result.n_f)
 
 
-def _obs_block(comparisons, seconds, tracer, i0) -> dict:
+def _obs_block(comparisons, seconds, tracer, i0, jit0) -> dict:
     """The normalized ``meta["obs"]`` block every campaign result carries.
 
     Always: achieved ``comparisons``, wall ``seconds``,
-    ``comparisons_per_s``.  When tracing was enabled for the run, also the
-    per-phase breakdown (from the span events recorded since index ``i0``)
-    and — when the core engines recorded roofline events — the summed
-    ``bound_seconds``, the binding ``bottleneck`` term, and
-    ``utilization`` = bound / measured device-phase seconds (1.0 means
-    running AT the cost-model bound)."""
+    ``comparisons_per_s``, and ``jit``, the JAX lowerings and compiles
+    counted in this process since ``jit0`` was read (a campaign that
+    reuses its compiled program reads 0 and 0).  When tracing was enabled
+    for the run, also the per-phase breakdown from the span events
+    recorded since index ``i0``."""
+    jit = jit_counts()
     block = {
         "comparisons": int(comparisons),
         "seconds": float(seconds),
         "comparisons_per_s": float(comparisons) / max(float(seconds), 1e-12),
+        "jit": {k: jit[k] - jit0[k] for k in jit},
     }
     if tracer is None:
         return block
-    events = tracer.events(i0)
-    phases = obs.aggregate_phases(events)
     block["phases"] = {
         n: {"count": int(p["count"]), "seconds": float(p["seconds"])}
-        for n, p in sorted(phases.items()) if n != "roofline"
+        for n, p in sorted(tracer.phase_stats(i0).items())
     }
-    bound, bottleneck = 0.0, None
-    for ph, name, _ts, _tid, args in events:
-        if ph == "E" and name == "roofline" and args:
-            bound += float(args.get("bound_seconds", 0.0))
-            bottleneck = args.get("bottleneck", bottleneck)
-    if bound > 0.0:
-        block["bound_seconds"] = bound
-        block["bottleneck"] = bottleneck
-        measured = sum(
-            p["seconds"] for n, p in phases.items()
-            if n in ("ring-step", "delta-border")
-        )
-        if measured > 0.0:
-            block["utilization"] = bound / measured
     return block
 
 
@@ -130,6 +116,7 @@ class SimilarityEngine:
         self._mesh = mesh
         self._devices = devices
         self._mesh_cache = {}
+        count_jit_events()
 
     # -- internals ---------------------------------------------------------
 
@@ -158,6 +145,23 @@ class SimilarityEngine:
             )
         return self._mesh_cache[key]
 
+    def _observed(self, run, *args):
+        """``run(*args)`` inside a ``campaign`` span, with the result's
+        ``meta["obs"]`` block attached.  Counting the results scans every
+        tile once (the ``count`` span, around ``entries`` spans)."""
+        tracer = obs.get_tracer()
+        i0 = tracer.event_count() if tracer is not None else 0
+        jit0 = jit_counts()
+        t0 = time.perf_counter()
+        with obs.span("campaign"):
+            result = run(*args)
+        with obs.span("count"):
+            comparisons = _campaign_comparisons(result)
+        result.meta["obs"] = _obs_block(
+            comparisons, time.perf_counter() - t0, tracer, i0, jit0,
+        )
+        return result
+
     # -- public API --------------------------------------------------------
 
     def run(self, request: SimilarityRequest, V=None) -> SimilarityResult:
@@ -174,23 +178,15 @@ class SimilarityEngine:
         payload never materializes in host memory beyond the double
         buffers, and ``meta["stream"]`` records the chunk accounting.
 
-        Every result's ``meta["obs"]`` records achieved comparisons/s;
-        under an enabled ``repro.obs`` tracer it adds the per-phase
-        breakdown and roofline utilization (docs/OBSERVABILITY.md)."""
+        Every result's ``meta["obs"]`` records achieved comparisons/s and
+        the campaign's JAX lowerings and compiles; under an enabled
+        ``repro.obs`` tracer it adds the per-phase breakdown
+        (docs/OBSERVABILITY.md)."""
         if request.delta_from:
             # load() verifies the prior's checksum before we merge into it
             prior = SimilarityResult.load(request.delta_from)
             return self.run_delta(request, prior, V)
-        tracer = obs.get_tracer()
-        i0 = tracer.event_count() if tracer is not None else 0
-        t0 = time.perf_counter()
-        with obs.span("campaign"):
-            result = self._run(request, V)
-        result.meta["obs"] = _obs_block(
-            _campaign_comparisons(result), time.perf_counter() - t0,
-            tracer, i0,
-        )
-        return result
+        return self._observed(self._run, request, V)
 
     def _run(self, request: SimilarityRequest, V=None) -> SimilarityResult:
         from repro.kernels.mgemm_levels.planes import PackedPlanes
@@ -293,16 +289,7 @@ class SimilarityEngine:
         The merged result round-trips ``save()/load()`` as a single-rank
         packed result and is itself a valid prior for the next append
         (deltas chain)."""
-        tracer = obs.get_tracer()
-        i0 = tracer.event_count() if tracer is not None else 0
-        t0 = time.perf_counter()
-        with obs.span("campaign"):
-            result = self._run_delta(request, prior, V)
-        result.meta["obs"] = _obs_block(
-            _campaign_comparisons(result), time.perf_counter() - t0,
-            tracer, i0,
-        )
-        return result
+        return self._observed(self._run_delta, request, prior, V)
 
     def _run_delta(self, request, prior, V=None) -> SimilarityResult:
         from repro.core.delta import merge_delta, twoway_delta

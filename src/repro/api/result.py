@@ -27,6 +27,7 @@ from repro.core.plan2 import TwoWayPlan
 from repro.core.plan3 import ThreeWayPlan
 from repro.core.threeway import ThreeWayOutput
 from repro.core.twoway import TwoWayOutput
+from repro.obs import trace as obs
 
 __all__ = ["Tile", "SimilarityResult"]
 
@@ -104,12 +105,16 @@ class SimilarityResult:
         return out
 
     def checksum(self) -> int:
-        """Paper §5 exact campaign checksum (all stages combined)."""
+        """Paper §5 exact campaign checksum (all stages combined).
+
+        Each tile's hash is a ``hash`` span; the tiles' index assembly
+        shows as the engines' ``entries`` spans."""
         if self._checksum is None:
             parts = []
             count = 0
             for t in self.tiles():
-                parts.append(t.raw_checksum())
+                with obs.span("hash"):
+                    parts.append(t.raw_checksum())
                 count += len(t)
             self._checksum = ck.combine(parts)
             self._num_results = count

@@ -259,16 +259,15 @@ def twoway_delta(
             check_vma=False,
         ),
     )
+    payload_bytes = sum(int(a.nbytes) for a in args)
     with obs.span("delta-border") as sp:
-        rect, tri = obs.fence(fn(*args))
-        sp.add(n_old=int(n_old), n_new=int(m),
-               payload_bytes=sum(int(a.nbytes) for a in args))
-    obs.roofline_event(fn, args, int(mesh.devices.size))
+        rect, tri = fn(*args)
+        rect, tri = np.asarray(rect), np.asarray(tri)[0]
+        sp.add(n_old=int(n_old), n_new=int(m), payload_bytes=payload_bytes)
     info = delta_accounting(
-        cfg, n_old=n_old, n_new=m, n_op=n_op,
-        payload_bytes=sum(int(a.nbytes) for a in args),
+        cfg, n_old=n_old, n_new=m, n_op=n_op, payload_bytes=payload_bytes,
     )
-    return np.asarray(rect), np.asarray(tri)[0], cfg, info
+    return rect, tri, cfg, info
 
 
 def merge_delta(

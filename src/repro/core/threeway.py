@@ -59,7 +59,7 @@ from repro.core.metric_spec import (
 )
 from repro.core.plan3 import ItemKind, ThreeWayPlan, PERMS
 from repro.core.tile_executor import TileExecutor
-from repro.core.twoway import CometConfig, batch_accounting
+from repro.core.twoway import CometConfig, _run_program, batch_accounting
 from repro.obs import trace as obs
 
 __all__ = [
@@ -421,45 +421,57 @@ class ThreeWayOutput:
     stage: int
 
     def entries(self):
-        """Yield (i, j, k, value) for every unique computed triple."""
+        """Yield (i, j, k, value) for every unique computed triple.
+
+        Each item's index and value gather is an ``entries`` span, closed
+        before the item is yielded."""
         n_pv, n_pr = self.plan.n_pv, self.plan.n_pr
-        m = self.n_vp
         L = self.blocks.shape[3]
-        li = np.arange(m)
+        li = np.arange(self.n_vp)
         for p_v in range(n_pv):
             for p_r in range(n_pr):
                 items = self.plan.items_of(p_v, p_r)
                 assert len(items) <= self.blocks.shape[2]
                 for slot, it in enumerate(items):
-                    own, bj, bk = it.blocks(p_v, n_pv)
-                    lo, _ = self.plan.sixth_bounds(m, it.slice_idx, self.stage)
-                    jg = lo + np.arange(L)
-                    vals = self.blocks[p_v, p_r, slot]  # (L, m, m)
-                    if it.kind == ItemKind.DIAG:
-                        pipe_b = left_b = right_b = own
-                        mask = (li[None, :, None] < jg[:, None, None]) & (
-                            li[None, None, :] > jg[:, None, None]
-                        )
-                    elif it.kind == ItemKind.FACE:
-                        pipe_b, left_b, right_b = bj, own, bj
-                        mask = np.broadcast_to(
-                            li[None, None, :] > jg[:, None, None], vals.shape
-                        )
-                    else:
-                        if it.slice_axis == 0:
-                            pipe_b, left_b, right_b = own, bj, bk
-                        elif it.slice_axis == 1:
-                            pipe_b, left_b, right_b = bj, own, bk
-                        else:
-                            pipe_b, left_b, right_b = bk, own, bj
-                        mask = np.ones(vals.shape, bool)
-                    T, Ll, R = np.meshgrid(jg, li, li, indexing="ij")
-                    gi = pipe_b * m + T
-                    gj = left_b * m + Ll
-                    gk = right_b * m + R
-                    mask = mask & (gi < self.n_v) & (gj < self.n_v) & (gk < self.n_v)
-                    if mask.any():
-                        yield gi[mask], gj[mask], gk[mask], vals[mask]
+                    with obs.span("entries"):
+                        entry = self._item_entries(p_v, p_r, slot, it, L, li)
+                    if entry is not None:
+                        yield entry
+
+    def _item_entries(self, p_v, p_r, slot, it, L, li):
+        """(i, j, k, value) arrays of one computed item, or None when it
+        holds no triple below ``n_v``."""
+        m = self.n_vp
+        own, bj, bk = it.blocks(p_v, self.plan.n_pv)
+        lo, _ = self.plan.sixth_bounds(m, it.slice_idx, self.stage)
+        jg = lo + np.arange(L)
+        vals = self.blocks[p_v, p_r, slot]  # (L, m, m)
+        if it.kind == ItemKind.DIAG:
+            pipe_b = left_b = right_b = own
+            mask = (li[None, :, None] < jg[:, None, None]) & (
+                li[None, None, :] > jg[:, None, None]
+            )
+        elif it.kind == ItemKind.FACE:
+            pipe_b, left_b, right_b = bj, own, bj
+            mask = np.broadcast_to(
+                li[None, None, :] > jg[:, None, None], vals.shape
+            )
+        else:
+            if it.slice_axis == 0:
+                pipe_b, left_b, right_b = own, bj, bk
+            elif it.slice_axis == 1:
+                pipe_b, left_b, right_b = bj, own, bk
+            else:
+                pipe_b, left_b, right_b = bk, own, bj
+            mask = np.ones(vals.shape, bool)
+        T, Ll, R = np.meshgrid(jg, li, li, indexing="ij")
+        gi = pipe_b * m + T
+        gj = left_b * m + Ll
+        gk = right_b * m + R
+        mask = mask & (gi < self.n_v) & (gj < self.n_v) & (gk < self.n_v)
+        if not mask.any():
+            return None
+        return gi[mask], gj[mask], gk[mask], vals[mask]
 
     def dense(self) -> np.ndarray:
         out = np.zeros((self.n_v,) * 3, self.blocks.dtype)
@@ -503,29 +515,30 @@ def _prep_payload3(V, cfg: CometConfig, metric: MetricSpec):
         n_vp = -(-n_v // cfg.n_pv)
         n_vp += (-n_vp) % unit
         Pp = pad_planes(V.planes, byte_align=cfg.n_pf, n_v=cfg.n_pv * n_vp)
-        return cfg, jnp.asarray(Pp), P(None, "pf", "pv"), n_vp, n_v
+        with obs.span("stage"):
+            arg = jnp.asarray(Pp)
+        return cfg, arg, P(None, "pf", "pv"), n_vp, n_v
     n_v = V.shape[1]
-    V = np.asarray(V)
-    cfg = resolve_config(cfg, V, metric)
-    planes = cfg.encoding == "bitplane"
-    n_vp = -(-n_v // cfg.n_pv)
-    n_vp += (-n_vp) % unit
-    fp = (-V.shape[0]) % cfg.n_pf
-    Vp = np.pad(V, ((0, fp), (0, cfg.n_pv * n_vp - n_v)))
-    if planes:
-        # field_align pads fields to 8*n_pf so the BYTE axis splits
-        # evenly over "pf" (planes.py owns the rule); pad bits are inert
-        from repro.kernels.mgemm_levels import encode_bitplanes_np
+    with obs.span("encode") as sp:
+        V = np.asarray(V)
+        cfg = resolve_config(cfg, V, metric)
+        n_vp = -(-n_v // cfg.n_pv)
+        n_vp += (-n_vp) % unit
+        fp = (-V.shape[0]) % cfg.n_pf
+        Vp = np.pad(V, ((0, fp), (0, cfg.n_pv * n_vp - n_v)))
+        if cfg.encoding == "bitplane":
+            # field_align pads fields to 8*n_pf so the BYTE axis splits
+            # evenly over "pf" (planes.py owns the rule); pad bits are inert
+            from repro.kernels.mgemm_levels import encode_bitplanes_np
 
-        with obs.span("encode") as sp:
-            arg = jnp.asarray(
-                encode_bitplanes_np(Vp, cfg.levels, field_align=cfg.n_pf)
-            )
-            sp.add(bytes=int(arg.nbytes), levels=int(cfg.levels))
-        in_specs = P(None, "pf", "pv")
-    else:
-        arg = jnp.asarray(Vp, dtype=jnp.dtype(cfg.ring_dtype))
-        in_specs = P("pf", "pv")
+            host = encode_bitplanes_np(Vp, cfg.levels, field_align=cfg.n_pf)
+            dtype, in_specs = None, P(None, "pf", "pv")
+        else:
+            host = Vp
+            dtype, in_specs = jnp.dtype(cfg.ring_dtype), P("pf", "pv")
+        sp.add(bytes=int(host.nbytes), levels=int(cfg.levels))
+    with obs.span("stage"):
+        arg = jnp.asarray(host, dtype=dtype)
     return cfg, arg, in_specs, n_vp, n_v
 
 
@@ -551,14 +564,11 @@ def threeway_distributed(
         out_specs=P("pv", "pr", None, None, None, None),
         check_vma=False,
     )
-    jfn = jax.jit(fn, static_argnames=())
-    with obs.span("ring-step") as sp:
-        blocks = obs.fence(jfn(arg))
-        sp.add(stage=int(stage), payload_bytes=int(arg.nbytes))
-    obs.roofline_event(jfn, (arg,), int(mesh.devices.size))
     L = n_vp // (6 * cfg.n_st)
-    blocks = np.asarray(blocks).reshape(
-        cfg.n_pv, cfg.n_pr, plan.slots_per_rank, L, n_vp, n_vp
+    blocks = _run_program(
+        jax.jit(fn), arg,
+        (cfg.n_pv, cfg.n_pr, plan.slots_per_rank, L, n_vp, n_vp),
+        stage=int(stage),
     )
     return ThreeWayOutput(blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp, stage=stage)
 
@@ -590,16 +600,11 @@ def threeway_batched(
         out_specs=P("pv", "pr", None, None, None, None, None),
         check_vma=False,
     )
-    jfn = jax.jit(fn)
-    with obs.span("ring-step") as sp:
-        blocks = obs.fence(jfn(arg))
-        sp.add(stage=int(stage), payload_bytes=int(arg.nbytes),
-               metrics=len(flat))
-    obs.roofline_event(jfn, (arg,), int(mesh.devices.size))
-    blocks = np.asarray(blocks)
     L = n_vp // (6 * cfg.n_st)
-    blocks = blocks.reshape(
-        cfg.n_pv, cfg.n_pr, plan.slots_per_rank, len(flat), L, n_vp, n_vp
+    blocks = _run_program(
+        jax.jit(fn), arg,
+        (cfg.n_pv, cfg.n_pr, plan.slots_per_rank, len(flat), L, n_vp, n_vp),
+        stage=int(stage), metrics=len(flat),
     )
     by_name = {
         s.name: ThreeWayOutput(

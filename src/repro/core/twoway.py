@@ -341,18 +341,24 @@ class TwoWayOutput:
     # -- reads --------------------------------------------------------------
 
     def entries(self):
-        """Yield (i, j, value) for every unique computed pair (i < j)."""
+        """Yield (i, j, value) for every unique computed pair (i < j).
+
+        Each block's index and value gather is an ``entries`` span, closed
+        before the block is yielded."""
         n_pv, n_pr = self.plan.n_pv, self.plan.n_pr
         for p_v in range(n_pv):
             for p_r in range(n_pr):
                 for d in self.plan.steps_of_pr(p_r):
                     if not self.plan.rank_computes(p_v, p_r, d):
                         continue
-                    row, col = self.plan.block_of(p_v, d)
-                    I, J, mask = global_pairs_of_block(row, col, self.n_vp)
-                    mask = mask & (I < self.n_v) & (J < self.n_v)
-                    vals = self._block_values(p_v, p_r, d)
-                    yield I[mask], J[mask], vals[mask]
+                    with obs.span("entries"):
+                        row, col = self.plan.block_of(p_v, d)
+                        I, J, mask = global_pairs_of_block(row, col,
+                                                           self.n_vp)
+                        mask = mask & (I < self.n_v) & (J < self.n_v)
+                        vals = self._block_values(p_v, p_r, d)
+                        entry = I[mask], J[mask], vals[mask]
+                    yield entry
 
     def dense(self) -> np.ndarray:
         """(n_v, n_v) symmetric metric matrix (tests / small problems)."""
@@ -489,6 +495,24 @@ def _twoway_deferred_program(
     return out[None, None], s_own[None]
 
 
+def _run_program(fn, arg, shape, **attrs) -> np.ndarray:
+    """Run a jitted campaign program on its staged payload and read its
+    blocks back into a host array of ``shape``.
+
+    Three spans split the time: ``dispatch`` (the call until it returns:
+    trace, lowering, compile or cache load, enqueue), ``ring-step`` (the
+    wait for the device program) and ``readback`` (the copy to the host).
+    The wait costs nothing extra: the readback right after it would
+    block until the program is done anyway."""
+    with obs.span("dispatch"):
+        out = fn(arg)
+    with obs.span("ring-step") as sp:
+        jax.block_until_ready(out)
+        sp.add(payload_bytes=int(arg.nbytes), **attrs)
+    with obs.span("readback"):
+        return np.asarray(out).reshape(shape)
+
+
 def _prep_payload(V, cfg: CometConfig, metric: MetricSpec):
     """Resolve the config against V and build the sharded ring payload.
 
@@ -506,25 +530,29 @@ def _prep_payload(V, cfg: CometConfig, metric: MetricSpec):
             V.planes, byte_align=cfg.n_pf,
             n_v=n_v + (-n_v) % cfg.n_pv,
         )
-        return cfg, jnp.asarray(Pp), P(None, "pf", "pv"), True, \
+        with obs.span("stage"):
+            arg = jnp.asarray(Pp)
+        return cfg, arg, P(None, "pf", "pv"), True, \
             Pp.shape[2] // cfg.n_pv, n_v
     n_v = V.shape[1]
-    V = np.asarray(V)
-    cfg = resolve_config(cfg, V, metric)
-    planes = cfg.encoding == "bitplane"
-    if planes:
-        # encode ONCE before shard_map; the byte axis shards over "pf"
-        from repro.kernels.mgemm_levels import encode_bitplanes_np
+    with obs.span("encode") as sp:
+        V = np.asarray(V)
+        cfg = resolve_config(cfg, V, metric)
+        planes = cfg.encoding == "bitplane"
+        if planes:
+            # encode ONCE before shard_map; the byte axis shards over "pf"
+            from repro.kernels.mgemm_levels import encode_bitplanes_np
 
-        Vp = pad_vectors(V, cfg, field_align=8)
-        with obs.span("encode") as sp:
-            arg = jnp.asarray(encode_bitplanes_np(Vp, cfg.levels))
-            sp.add(bytes=int(arg.nbytes), levels=int(cfg.levels))
-        in_specs = P(None, "pf", "pv")
-    else:
-        Vp = pad_vectors(V, cfg)
-        arg = jnp.asarray(Vp, dtype=jnp.dtype(cfg.ring_dtype))
-        in_specs = P("pf", "pv")
+            Vp = pad_vectors(V, cfg, field_align=8)
+            host, dtype = encode_bitplanes_np(Vp, cfg.levels), None
+            in_specs = P(None, "pf", "pv")
+        else:
+            Vp = pad_vectors(V, cfg)
+            host, dtype = Vp, jnp.dtype(cfg.ring_dtype)
+            in_specs = P("pf", "pv")
+        sp.add(bytes=int(host.nbytes), levels=int(cfg.levels))
+    with obs.span("stage"):
+        arg = jnp.asarray(host, dtype=dtype)
     return cfg, arg, in_specs, planes, Vp.shape[1] // cfg.n_pv, n_v
 
 
@@ -553,12 +581,9 @@ def twoway_distributed(
             check_vma=False,
         ),
     )
-    with obs.span("ring-step") as sp:
-        blocks = obs.fence(fn(arg))
-        sp.add(steps=int(plan.n_steps), payload_bytes=int(arg.nbytes))
-    obs.roofline_event(fn, (arg,), int(mesh.devices.size))
-    blocks = np.asarray(blocks).reshape(
-        cfg.n_pv, cfg.n_pr, plan.slots_per_rank, n_vp, n_vp
+    blocks = _run_program(
+        fn, arg, (cfg.n_pv, cfg.n_pr, plan.slots_per_rank, n_vp, n_vp),
+        steps=int(plan.n_steps),
     )
     return TwoWayOutput(blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp)
 
@@ -701,14 +726,10 @@ def twoway_batched(
         out_specs=P("pv", "pr", None, None, None, None),
         check_vma=False,
     )
-    jfn = jax.jit(fn)
-    with obs.span("ring-step") as sp:
-        blocks = obs.fence(jfn(arg))
-        sp.add(steps=int(plan.n_steps), payload_bytes=int(arg.nbytes),
-               metrics=len(flat))
-    obs.roofline_event(jfn, (arg,), int(mesh.devices.size))
-    blocks = np.asarray(blocks).reshape(
-        cfg.n_pv, cfg.n_pr, len(flat), plan.slots_per_rank, n_vp, n_vp
+    blocks = _run_program(
+        jax.jit(fn), arg,
+        (cfg.n_pv, cfg.n_pr, len(flat), plan.slots_per_rank, n_vp, n_vp),
+        steps=int(plan.n_steps), metrics=len(flat),
     )
     by_name = {
         s.name: TwoWayOutput(

@@ -103,7 +103,7 @@ def _report_batched(batched, request, args) -> int:
 def _report_trace(tracer, result, args) -> None:
     """--trace epilogue: write the Chrome trace file, print the per-phase
     table (every canonical phase, count 0 when it never ran) and the
-    roofline-utilization line from ``meta["obs"]``."""
+    throughput and JAX lowering/compile counts from ``meta["obs"]``."""
     if tracer is None:
         return
     from repro.obs import trace as obs_trace
@@ -112,14 +112,10 @@ def _report_trace(tracer, result, args) -> None:
     tracer.write_chrome_trace(args.trace)
     print(obs_trace.format_phase_table(tracer.phase_stats()))
     ob = result.meta.get("obs") or {}
-    line = (f"obs comparisons={ob.get('comparisons')} "
-            f"rate={ob.get('comparisons_per_s', 0.0):.3e} comparisons/s")
-    if "bound_seconds" in ob:
-        line += (f" bound_seconds={ob['bound_seconds']:.6f}"
-                 f" bottleneck={ob.get('bottleneck')}")
-    if "utilization" in ob:
-        line += f" utilization={ob['utilization']:.3e}"
-    print(line)
+    jit = ob.get("jit") or {}
+    print(f"obs comparisons={ob.get('comparisons')} "
+          f"rate={ob.get('comparisons_per_s', 0.0):.3e} comparisons/s "
+          f"lowerings={jit.get('lowerings')} compiles={jit.get('compiles')}")
     print(f"trace={args.trace} events={tracer.event_count()}")
 
 
@@ -211,8 +207,8 @@ def main(argv=None):
                     help="record per-phase spans (repro.obs) during the "
                          "campaign, write Chrome/Perfetto trace-event JSON "
                          "to OUT.json, and print the phase table plus "
-                         "roofline utilization after the run; checksums are "
-                         "unchanged (tracing only adds timing fences)")
+                         "the JAX lowering and compile counts after the "
+                         "run; checksums are unchanged")
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
 

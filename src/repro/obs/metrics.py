@@ -23,7 +23,9 @@ __all__ = [
     "Gauge",
     "Histogram",
     "MetricsRegistry",
+    "count_jit_events",
     "default_registry",
+    "jit_counts",
 ]
 
 
@@ -176,3 +178,44 @@ def default_registry() -> MetricsRegistry:
     """The process-wide registry (components may also own private ones —
     ``SimilarityService`` does, so tests and services never share state)."""
     return _DEFAULT
+
+
+#: ``jax.monitoring`` duration events counted into ``default_registry()``:
+#: one jaxpr lowered to an MLIR module, one backend compile (a persistent
+#: cache hit included: JAX times the cache lookup under the same event).
+JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lowerings",
+    "/jax/core/compile/backend_compile_duration": "jit.compiles",
+}
+_jit_counting = False
+_jit_lock = threading.Lock()
+
+
+def count_jit_events() -> None:
+    """Count JAX lowerings and compiles in ``default_registry()`` from now
+    on.  Registers one ``jax.monitoring`` listener per process; later calls
+    do nothing."""
+    global _jit_counting
+    with _jit_lock:
+        if _jit_counting:
+            return
+        import jax.monitoring
+
+        counters = {event: _DEFAULT.counter(name)
+                    for event, name in JIT_EVENTS.items()}
+
+        def listener(event, duration_secs, **kwargs):
+            c = counters.get(event)
+            if c is not None:
+                c.inc()
+
+        jax.monitoring.register_event_duration_secs_listener(listener)
+        _jit_counting = True
+
+
+def jit_counts() -> dict:
+    """``{"lowerings": n, "compiles": n}`` counted so far in this process
+    (zeros before ``count_jit_events``)."""
+    with _DEFAULT.locked():
+        return {name.split(".", 1)[1]: _DEFAULT.counter(name).value
+                for name in JIT_EVENTS.values()}

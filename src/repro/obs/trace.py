@@ -1,13 +1,23 @@
 """Thread-aware span tracer with zero overhead when disabled.
 
+One span API, two sinks.  ``span(name)`` records into whichever is on:
+
+* the Chrome tracer installed by ``enable()`` — B/E events under
+  ``name``, which ``meta["obs"]["phases"]`` and the CLI table read;
+* a JAX profiler session (``jax.profiler.trace`` / ``start_trace``) — a
+  ``jax.profiler.TraceAnnotation`` named ``"repro." + name``, which lands
+  in the session's ``.xplane.pb`` on the same clock as the device ops,
+  its seconds also observed in ``default_registry()``'s
+  ``"span." + name`` histogram.
+
 Design constraints (pinned by tests/test_obs.py):
 
-* **Disabled is free.**  The module-global ``_tracer`` is ``None`` by
-  default and ``span()`` returns ONE shared no-op singleton — a traced
-  call site costs a global read and a ``is None`` branch, with no
-  allocation, no lock and no clock read.  The engines therefore leave
-  their span calls in place permanently; campaign checksums and hot-path
-  timings are untouched unless a tracer is installed.
+* **Disabled is free.**  With neither sink on, ``span()`` returns ONE
+  shared no-op singleton — a traced call site costs a global read, an
+  ``is None`` branch and the profiler's static ``is_enabled()`` check,
+  with no allocation, no lock and no clock read.  The engines therefore
+  leave their span calls in place permanently; campaign checksums and
+  hot-path timings are untouched by either sink.
 
 * **Thread-aware.**  Events carry ``threading.get_ident()`` as the
   Chrome ``tid``; span nesting is tracked in a ``contextvars.ContextVar``
@@ -22,11 +32,10 @@ Design constraints (pinned by tests/test_obs.py):
   ``validate_chrome_trace`` is the stdlib-only schema checker CI runs on
   the exported file.
 
-* **Device time.**  Wall time around an async XLA dispatch measures the
-  enqueue, not the compute; ``fence(x)`` calls ``jax.block_until_ready``
-  — only when tracing is enabled — so a span closed after a fence reads
-  true device time.  With tracing off the fence is a no-op and XLA's
-  async scheduling is undisturbed.
+* **No added synchronisation.**  Spans never wait for the device.  The
+  engines' ``ring-step`` span covers a ``block_until_ready`` that runs
+  whether or not anything is tracing, because the host read on the next
+  line would block anyway.
 """
 from __future__ import annotations
 
@@ -36,6 +45,8 @@ import os
 import threading
 import time
 
+from repro.obs.metrics import default_registry
+
 __all__ = [
     "Tracer",
     "aggregate_phases",
@@ -43,10 +54,8 @@ __all__ = [
     "disable",
     "enable",
     "enabled",
-    "fence",
     "format_phase_table",
     "get_tracer",
-    "roofline_event",
     "span",
     "validate_chrome_trace",
     "CANONICAL_PHASES",
@@ -59,17 +68,42 @@ __all__ = [
 CANONICAL_PHASES = (
     "validate",
     "encode",
+    "stage",
+    "dispatch",
     "prefetch-stage",
     "ring-step",
+    "readback",
     "delta-border",
     "merge",
+    "count",
+    "entries",
+    "hash",
 )
+
+#: Prefix of every span's name in the profiler sink.
+PROFILER_PREFIX = "repro."
+#: Prefix of the ``default_registry()`` histogram that totals the seconds
+#: of each span recorded in the profiler sink.
+REGISTRY_PREFIX = "span."
 
 _tracer: "Tracer | None" = None  # None == disabled (the zero-overhead path)
 
 _SPAN_STACK: contextvars.ContextVar[tuple] = contextvars.ContextVar(
     "repro_obs_span_stack", default=()
 )
+
+#: ``jax.profiler.TraceAnnotation``, imported on the first ``span()`` call
+#: so that importing this module does not import jax.
+_annotation = None
+
+
+def _profiler_annotation():
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
 
 
 class _NullSpan:
@@ -90,13 +124,40 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("_tracer", "name", "_attrs", "_token")
+class _ProfilerSpan:
+    """A span in the profiler sink: the ``repro.<name>`` annotation, and
+    its seconds observed in ``default_registry()``'s ``span.<name>``
+    histogram, so a profiled process reads its own split without parsing
+    the trace."""
 
-    def __init__(self, tracer, name, attrs):
+    __slots__ = ("_ann", "_seconds", "_t0")
+
+    def __init__(self, name):
+        self._ann = _profiler_annotation()(PROFILER_PREFIX + name)
+        self._seconds = default_registry().histogram(REGISTRY_PREFIX + name)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._seconds.observe(time.perf_counter() - self._t0)
+        self._ann.__exit__(*exc)
+        return False
+
+    def add(self, **attrs):
+        return self  # attributes are Chrome-only
+
+
+class _Span:
+    __slots__ = ("_tracer", "name", "_attrs", "_token", "_profiled")
+
+    def __init__(self, tracer, name, attrs, profiler=False):
         self._tracer = tracer
         self.name = name
         self._attrs = dict(attrs) if attrs else {}
+        self._profiled = _ProfilerSpan(name) if profiler else None
 
     def add(self, **attrs):
         """Attach attributes (byte counts, step counts, ...) to the span;
@@ -109,9 +170,13 @@ class _Span:
         self._token = _SPAN_STACK.set(stack + (self.name,))
         args = {"parent": "/".join(stack)} if stack else None
         self._tracer._emit("B", self.name, self._tracer._clock(), args)
+        if self._profiled is not None:
+            self._profiled.__enter__()
         return self
 
     def __exit__(self, *exc):
+        if self._profiled is not None:
+            self._profiled.__exit__(*exc)
         self._tracer._emit(
             "E", self.name, self._tracer._clock(), self._attrs or None
         )
@@ -130,8 +195,11 @@ class Tracer:
 
     # -- recording -----------------------------------------------------------
 
-    def span(self, name: str, attrs: dict = None) -> _Span:
-        return _Span(self, name, attrs)
+    def span(self, name: str, attrs: dict = None,
+             profiler: bool = False) -> _Span:
+        """A span recorded here and, with ``profiler``, also in the
+        active profiler session."""
+        return _Span(self, name, attrs, profiler)
 
     def _emit(self, ph, name, ts_ns, args):
         tid = threading.get_ident()
@@ -225,60 +293,22 @@ def get_tracer() -> "Tracer | None":
 def span(name: str, attrs: dict = None):
     """Open a span: ``with span("encode", {"bytes": n}) as sp: ...``.
 
-    Disabled, this returns the shared no-op singleton — no allocation.
-    (The ``attrs`` dict literal at an instrumented call site WOULD
-    allocate even when disabled; hot paths therefore pass attrs via
+    Records into the Chrome tracer when one is installed and into the
+    active profiler session (as ``"repro." + name``) when there is one.
+    With neither, this returns the shared no-op singleton — no
+    allocation.  (The ``attrs`` dict literal at an instrumented call site
+    WOULD allocate even when disabled; hot paths therefore pass attrs via
     ``sp.add(...)`` inside the span or not at all.)"""
+    profiling = (_annotation or _profiler_annotation()).is_enabled()
     t = _tracer
     if t is None:
-        return _NULL_SPAN
-    return t.span(name, attrs)
+        return _ProfilerSpan(name) if profiling else _NULL_SPAN
+    return t.span(name, attrs, profiling)
 
 
 def current_path() -> tuple:
     """The context's open-span name stack (propagates with copy_context)."""
     return _SPAN_STACK.get()
-
-
-def fence(x):
-    """``jax.block_until_ready(x)`` — only when tracing is enabled — so the
-    enclosing span measures device time, not dispatch time."""
-    if _tracer is not None:
-        import jax
-
-        jax.block_until_ready(x)
-    return x
-
-
-def roofline_event(jitted, args, n_devices: int, repeats: int = 1) -> None:
-    """Record the roofline cost bound of ``repeats`` calls of
-    ``jitted(*args)`` as a zero-length ``roofline`` span (attrs:
-    ``bound_seconds``, per-term seconds, bottleneck).  No-op when tracing
-    is disabled; best-effort when enabled (lower/compile is allowed to
-    fail off-path).  Streamed campaigns pass the chunk program once with
-    ``repeats=n_chunks``."""
-    t = _tracer
-    if t is None:
-        return
-    try:
-        compiled = jitted.lower(*args).compile()
-        from repro.roofline.analysis import analyze_compiled
-
-        terms = analyze_compiled(compiled, n_devices)
-    except Exception:
-        return
-    bound = max(terms["t_compute"], terms["t_memory"], terms["t_collective"])
-    ts = t._clock()
-    t.complete("roofline", ts, ts, {
-        "bound_seconds": bound * repeats,
-        "t_compute": terms["t_compute"],
-        "t_memory": terms["t_memory"],
-        "t_collective": terms["t_collective"],
-        "bottleneck": terms["bottleneck"],
-        "flops_per_device": terms["flops_per_device"],
-        "n_devices": n_devices,
-        "repeats": repeats,
-    })
 
 
 # -- aggregation + formatting -------------------------------------------------
@@ -308,7 +338,7 @@ def format_phase_table(phases: dict) -> str:
     spans (a merge inside a campaign) each report their own wall time.
     """
     names = list(CANONICAL_PHASES) + sorted(
-        n for n in phases if n not in CANONICAL_PHASES and n != "roofline"
+        n for n in phases if n not in CANONICAL_PHASES
     )
     total = sum(phases.get(n, {}).get("seconds", 0.0) for n in names) or 1.0
     rows = ["phase            count     seconds    share"]

@@ -94,7 +94,7 @@ def _stream_info(splan: StreamPlan, cfg: CometConfig, n_shards: int) -> dict:
     }
 
 
-def _run_chunks(sh, splan: StreamPlan, jfn, accs, stat_acc, n_devices=1):
+def _run_chunks(sh, splan: StreamPlan, jfn, accs, stat_acc):
     """Drive the prefetch/compute loop: stage each chunk, run the deferred
     program, fold the fp32 partials into the host accumulators.
 
@@ -140,9 +140,6 @@ def _run_chunks(sh, splan: StreamPlan, jfn, accs, stat_acc, n_devices=1):
             "stall_seconds": pf.stall_seconds,
             "compute_seconds": compute_s,
         }
-    if obs.enabled():
-        obs.roofline_event(jfn, (jnp.asarray(buffers[0]),), n_devices,
-                           repeats=len(chunks))
     return sum(b.nbytes for b in buffers), overlap
 
 
@@ -200,9 +197,7 @@ def stream_twoway(
         (cfg.n_pv, cfg.n_pr, plan.slots_per_rank, n_vp, n_vp), np.float32
     )
     stats = np.zeros((cfg.n_pv, n_vp), np.float32)
-    staged, overlap = _run_chunks(
-        sh, splan, jfn, [acc], stats, n_devices=int(mesh.devices.size)
-    )
+    staged, overlap = _run_chunks(sh, splan, jfn, [acc], stats)
 
     # -- cross-shard merge epilogue: assemble once from complete partials --
     executor = TileExecutor(
@@ -314,9 +309,7 @@ def stream_threeway(
         np.zeros(shape + (n_vp, n_vp), np.float32),
     ]
     stats = np.zeros((cfg.n_pv, n_vp), np.float32)
-    staged, overlap = _run_chunks(
-        sh, splan, jfn, accs, stats, n_devices=int(mesh.devices.size)
-    )
+    staged, overlap = _run_chunks(sh, splan, jfn, accs, stats)
 
     # -- cross-shard merge epilogue (mask logic mirrors entries()) ---------
     executor = TileExecutor(cfg=cfg, metric=metric, out_dtype=out_dtype,
@@ -436,11 +429,6 @@ def stream_twoway_delta(
             "compute_seconds": compute_s,
         }
     staged = sum(b.nbytes for bufs in buffers for b in bufs)
-    if obs.enabled():
-        obs.roofline_event(
-            jfn, (jnp.asarray(buffers[0][0]), jnp.asarray(buffers[0][1])),
-            int(mesh.devices.size), repeats=len(chunks),
-        )
 
     executor = TileExecutor(
         cfg=cfg, metric=metric, out_dtype=jnp.dtype(cfg.out_dtype),
@@ -502,9 +490,7 @@ def stream_twoway_batched(dataset, mesh, cfg: CometConfig, specs) -> tuple:
         (cfg.n_pv, cfg.n_pr, G, plan.slots_per_rank, n_vp, n_vp), np.float32
     )
     stats = np.zeros((cfg.n_pv, G, n_vp), np.float32)
-    staged, overlap = _run_chunks(
-        sh, splan, jfn, [acc], stats, n_devices=int(mesh.devices.size)
-    )
+    staged, overlap = _run_chunks(sh, splan, jfn, [acc], stats)
 
     by_name = {}
     with obs.span("merge") as sp:
@@ -582,9 +568,7 @@ def stream_threeway_batched(
         np.zeros(shape + (n_vp, n_vp), np.float32),
     ]
     stats = np.zeros((cfg.n_pv, G, n_vp), np.float32)
-    staged, overlap = _run_chunks(
-        sh, splan, jfn, accs, stats, n_devices=int(mesh.devices.size)
-    )
+    staged, overlap = _run_chunks(sh, splan, jfn, accs, stats)
 
     by_name = {}
     with obs.span("merge") as sp:

@@ -284,7 +284,7 @@ def test_meta_schema_matches_emitted(tmp_path):
     finally:
         trace.disable()
     assert "batch" in batched.meta
-    # the traced run exercises the OPTIONAL obs keys (phases, bound, ...)
+    # the traced run exercises the OPTIONAL obs key (phases)
     assert "phases" in batched.meta["obs"]
     _assert_meta_documented(batched.meta, blocks, "batched+traced")
     for mname, sname, res in batched.campaigns:
@@ -297,12 +297,12 @@ def test_observability_docs_name_real_code():
     from repro.obs import metrics as obs_metrics
     from repro.obs import trace as obs_trace
 
-    for name in ("enable", "disable", "enabled", "span", "fence",
-                 "roofline_event", "format_phase_table",
-                 "validate_chrome_trace", "CANONICAL_PHASES", "Tracer"):
+    for name in ("enable", "disable", "enabled", "span",
+                 "format_phase_table", "validate_chrome_trace",
+                 "CANONICAL_PHASES", "PROFILER_PREFIX", "Tracer"):
         assert hasattr(obs_trace, name), name
     for name in ("Counter", "Gauge", "Histogram", "MetricsRegistry",
-                 "default_registry"):
+                 "default_registry", "count_jit_events", "jit_counts"):
         assert hasattr(obs_metrics, name), name
     from repro.serve.engine import SimilarityService
     for attr in ("stats", "metrics"):
@@ -315,7 +315,9 @@ def test_observability_docs_name_real_code():
     with open(os.path.join(REPO, "docs", "OBSERVABILITY.md")) as f:
         doc = f.read()
     for name in ("--trace", "--metrics-json", "prefetch-stage", "ring-step",
-                 "validate_chrome_trace", "bound_seconds", "utilization",
+                 "dispatch", "readback", "entries", "hash",
+                 "validate_chrome_trace", "jax.profiler.trace", "repro.",
+                 "jit.lowerings", "jit.compiles", "device_roofline",
                  "stall_seconds", "MetricsRegistry"):
         assert name in doc, f"OBSERVABILITY.md lost its {name!r} mention"
     # the CLI flags the doc quotes exist in the launchers' parsers

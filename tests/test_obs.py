@@ -2,10 +2,13 @@
 
 Pins the observability design constraints (docs/OBSERVABILITY.md):
 
-* **Disabled is free** — ``span()`` returns ONE shared no-op singleton
-  (no allocation), ``fence`` passes values through untouched, and a
-  traced-then-untraced campaign is checksum **bit-identical** on both
-  the streamed and the delta paths;
+* **Disabled is free** — with no tracer and no profiler session
+  ``span()`` returns ONE shared no-op singleton (no allocation), and a
+  traced-then-untraced campaign is checksum **bit-identical** on the
+  streamed and the delta paths, under either sink;
+* inside a JAX profiler session every span lands in the ``.xplane.pb``
+  as ``repro.<name>``, nested as the code nests it;
+* ``meta["obs"]["jit"]`` counts each campaign's lowerings and compiles;
 * spans nest through the contextvar stack and cross threads via
   ``copy_context`` — a ``ShardPrefetcher`` staging span and a
   ``SimilarityService`` worker span both record the submitting
@@ -20,7 +23,9 @@ Pins the observability design constraints (docs/OBSERVABILITY.md):
 """
 import os
 import threading
+import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -59,13 +64,49 @@ def test_disabled_span_is_shared_singleton():
         assert sp.add(bytes=10) is sp  # no-ops, chainable
 
 
-def test_disabled_fence_is_identity():
-    x = object()
-    assert trace.fence(x) is x
+def test_span_is_singleton_again_after_a_profiler_session(tmp_path):
+    """Inside a profiler session a span is a live annotation even with no
+    Chrome tracer; once the session ends, ``span()`` is free again."""
+    from jax.profiler import TraceAnnotation
+
+    with jax.profiler.trace(str(tmp_path)):
+        assert TraceAnnotation.is_enabled()
+        sp = trace.span("a")
+        assert sp is not trace.span("b")
+        with sp as inner:
+            assert inner.add(bytes=1) is inner
+    assert not TraceAnnotation.is_enabled()
+    assert trace.span("a") is trace.span("b")
 
 
-def test_disabled_roofline_is_noop():
-    trace.roofline_event(None, (), 1)  # would raise if it touched jitted
+@pytest.mark.parametrize("chrome", [False, True])
+def test_profiled_spans_total_in_the_registry(tmp_path, monkeypatch, chrome):
+    """Each span the profiler sink records also observes its seconds in
+    the default registry's ``span.<name>`` histogram; spans outside the
+    session add nothing there."""
+    from repro.obs import metrics
+
+    registry = metrics.MetricsRegistry()
+    monkeypatch.setattr(metrics, "_DEFAULT", registry)
+    if chrome:
+        trace.enable()
+    try:
+        with trace.span("outside"):
+            pass
+        with jax.profiler.trace(str(tmp_path)):
+            for _ in range(3):
+                with trace.span("outer"):
+                    with trace.span("inner"):
+                        time.sleep(0.002)
+        with trace.span("outside"):
+            pass
+    finally:
+        trace.disable()
+    snap = registry.snapshot()
+    assert set(snap) == {"span.outer", "span.inner"}
+    assert snap["span.outer"]["count"] == snap["span.inner"]["count"] == 3
+    assert snap["span.inner"]["sum"] >= 3 * 0.002
+    assert snap["span.outer"]["sum"] >= snap["span.inner"]["sum"]
 
 
 # -- enabled: nesting, attrs, aggregation ------------------------------------
@@ -212,7 +253,7 @@ def _check_forest(forest):
     for node in forest:
         _emit(node)
     ts = t._clock()
-    t.complete("roofline", ts, ts, {"bound_seconds": 0.0})
+    t.complete("external", ts, ts, {"seconds": 0.0})
     trace.disable()
     payload = t.chrome_trace()
     assert trace.validate_chrome_trace(payload) == t.event_count()
@@ -274,13 +315,12 @@ def test_phase_table_always_prints_canonical_rows():
     assert lines[0].split() == ["phase", "count", "seconds", "share"]
     for name in trace.CANONICAL_PHASES:
         assert any(ln.startswith(name + " ") for ln in lines[1:]), name
-    # recorded extras appear; roofline never does
+    # recorded extras appear after the canonical rows
     table = trace.format_phase_table({
-        "roofline": {"count": 2, "seconds": 0.0},
         "campaign": {"count": 1, "seconds": 2.0},
         "ring-step": {"count": 4, "seconds": 1.0},
     })
-    assert "campaign" in table and "roofline" not in table
+    assert table.splitlines()[-1].startswith("campaign ")
     row = next(ln for ln in table.splitlines() if ln.startswith("ring-step"))
     assert row.split() == ["ring-step", "4", "1.000000", "33.3%"]
 
@@ -314,14 +354,13 @@ def test_traced_streamed_campaign_is_bit_identical(tmp_path):
     # ...and always-on overlap accounting
     assert plain.meta["stream"]["stall_seconds"] >= 0.0
     assert plain.meta["stream"]["compute_seconds"] > 0.0
-    # traced run: per-phase breakdown + roofline-bound utilization
+    # traced run: per-phase breakdown beside the always-on jit counts
     obs_traced = traced.meta["obs"]
     phases = obs_traced["phases"]
     assert phases["ring-step"]["count"] == plain.meta["stream"]["chunks"]
     assert phases["prefetch-stage"]["count"] == phases["ring-step"]["count"]
     assert phases["merge"]["count"] == 1 and "encode" not in phases
-    assert obs_traced["bound_seconds"] > 0.0
-    assert obs_traced["utilization"] > 0.0
+    assert set(obs_traced["jit"]) == {"lowerings", "compiles"}
     assert trace.validate_chrome_trace(t.chrome_trace()) == t.event_count()
 
 
@@ -352,3 +391,114 @@ def test_traced_delta_campaign_is_bit_identical(tmp_path):
     d = traced.meta["delta"]
     assert traced.meta["obs"]["comparisons"] == d["computed_entries"] * 32
     assert trace.validate_chrome_trace(t.chrome_trace()) == t.event_count()
+
+
+# -- the profiler sink --------------------------------------------------------
+
+_ENGINE_SPANS = {"validate", "encode", "stage", "dispatch", "ring-step",
+                 "readback"}
+
+
+def _campaign_request(way):
+    extra = {"n_st": 2, "stages": (0,)} if way == 3 else {}
+    return SimilarityRequest(way=way, metric="czekanowski", impl="levels",
+                             levels=2, encoding="bitplane", **extra)
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn()`` inside a JAX profiler session; return its result and
+    the session's ``repro.*`` host events as (name, start, end)."""
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        out = fn()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    events = [(ev.name[len(trace.PROFILER_PREFIX):], ev.start_ns,
+               ev.start_ns + ev.duration_ns)
+              for plane in data.planes if plane.name.startswith("/host")
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith(trace.PROFILER_PREFIX)]
+    return out, events
+
+
+@pytest.mark.parametrize("way,chrome", [(2, False), (3, True)])
+def test_profiler_sink_nests_repro_spans(tmp_path, way, chrome):
+    """Every engine and result span lands in the profiler trace as
+    ``repro.<name>``; the engine's nest inside ``repro.campaign``, the
+    result count's ``entries`` inside ``repro.count``, no two spans
+    overlap without nesting, and the checksum is the untraced one, with
+    or without the Chrome tracer on as well."""
+    V = random_integer_vectors(64, 36, max_value=2, seed=11)
+    engine = SimilarityEngine()
+    plain = engine.run(_campaign_request(way), V).checksum()
+
+    def campaign():
+        result = engine.run(_campaign_request(way), V)
+        return result.checksum()
+
+    tracer = trace.enable() if chrome else None
+    try:
+        checksum, events = _profiled(tmp_path, campaign)
+    finally:
+        trace.disable()
+    assert checksum == plain
+    names = {n for n, _, _ in events}
+    assert _ENGINE_SPANS | {"campaign", "count", "entries", "hash"} <= names
+    (c0, c1), = [(s, e) for n, s, e in events if n == "campaign"]
+    (n0, n1), = [(s, e) for n, s, e in events if n == "count"]
+    for n, s, e in events:
+        if n in _ENGINE_SPANS:
+            assert c0 <= s and e <= c1, n
+    # the result count's scan follows the campaign, before the checksum's
+    assert c1 <= n0
+    assert any(n == "entries" and n0 <= s and e <= n1 for n, s, e in events)
+    assert all(n1 <= s for n, s, e in events if n == "hash")
+    for (n1, s1, e1) in events:
+        for (n2, s2, e2) in events:
+            # any two spans are disjoint or one holds the other
+            assert e1 <= s2 or e2 <= s1 or (s1 <= s2 and e2 <= e1) \
+                or (s2 <= s1 and e1 <= e2), (n1, n2)
+    if chrome:
+        recorded = {e[1] for e in tracer.events()}
+        assert _ENGINE_SPANS | {"campaign", "entries", "hash"} <= recorded
+
+
+@pytest.mark.parametrize("source", ["streamed", "delta"])
+def test_profiled_campaign_is_bit_identical(tmp_path, source):
+    path = os.path.join(str(tmp_path), "ds")
+    write_dataset(path, random_integer_vectors(64, 20, max_value=2, seed=7),
+                  levels=2, n_shards=2)
+    engine = SimilarityEngine()
+    req = _streamed_request(path)
+    if source == "streamed":
+        def campaign():
+            return engine.run(req)
+    else:
+        prior = engine.run(req)
+        append_dataset(path, random_integer_vectors(64, 5, max_value=2,
+                                                    seed=9))
+
+        def campaign():
+            return engine.run_delta(req, prior)
+    plain = campaign()
+    profiled, events = _profiled(tmp_path / "trace", campaign)
+    assert profiled.checksum() == plain.checksum()
+    span = "ring-step" if source == "streamed" else "delta-border"
+    assert {"campaign", span, "merge"} <= {n for n, _, _ in events}
+
+
+@pytest.mark.parametrize("way", [2, 3])
+def test_campaign_jit_counts(way):
+    """``meta["obs"]["jit"]`` counts the campaign's own lowerings and
+    compiles: a repeated 2-way campaign reuses its cached program, while
+    the 3-way engine builds its program again on every call."""
+    V = random_integer_vectors(48, 24, max_value=2, seed=5)
+    engine = SimilarityEngine()
+    first = engine.run(_campaign_request(way), V).meta["obs"]["jit"]
+    again = engine.run(_campaign_request(way), V).meta["obs"]["jit"]
+    assert set(first) == set(again) == {"lowerings", "compiles"}
+    if way == 2:
+        assert again == {"lowerings": 0, "compiles": 0}
+    else:
+        assert again["lowerings"] >= 1 and again["compiles"] >= 1
