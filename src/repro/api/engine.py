@@ -26,7 +26,7 @@ from repro.api.result import SimilarityResult
 from repro.core.threeway import threeway_distributed
 from repro.core.twoway import twoway_distributed
 from repro.obs import trace as obs
-from repro.obs.metrics import count_jit_events, jit_counts
+from repro.obs.metrics import count_jit_events, default_registry, jit_counts
 from repro.parallel.mesh import COMET_AXES, make_comet_mesh
 
 __all__ = ["SimilarityEngine"]
@@ -48,21 +48,23 @@ def _campaign_comparisons(result) -> int:
     return int(result.num_results()) * int(result.n_f)
 
 
-def _obs_block(comparisons, seconds, tracer, i0, jit0) -> dict:
+def _obs_block(comparisons, seconds, tracer, i0, jit0, checksum) -> dict:
     """The normalized ``meta["obs"]`` block every campaign result carries.
 
     Always: achieved ``comparisons``, wall ``seconds``,
-    ``comparisons_per_s``, and ``jit``, the JAX lowerings and compiles
+    ``comparisons_per_s``, ``jit``, the JAX lowerings and compiles
     counted in this process since ``jit0`` was read (a campaign that
-    reuses its compiled program reads 0 and 0).  When tracing was enabled
-    for the run, also the per-phase breakdown from the span events
-    recorded since index ``i0``."""
+    reuses its compiled program reads 0 and 0), and ``checksum``, where
+    the result's checksum and count come from ("device" or "host").  When
+    tracing was enabled for the run, also the per-phase breakdown from the
+    span events recorded since index ``i0``."""
     jit = jit_counts()
     block = {
         "comparisons": int(comparisons),
         "seconds": float(seconds),
         "comparisons_per_s": float(comparisons) / max(float(seconds), 1e-12),
         "jit": {k: jit[k] - jit0[k] for k in jit},
+        "checksum": checksum,
     }
     if tracer is None:
         return block
@@ -147,8 +149,11 @@ class SimilarityEngine:
 
     def _observed(self, run, *args):
         """``run(*args)`` inside a ``campaign`` span, with the result's
-        ``meta["obs"]`` block attached.  Counting the results scans every
-        tile once (the ``count`` span, around ``entries`` spans)."""
+        ``meta["obs"]`` block attached.  Counting the results (the
+        ``count`` span) reads the count the device partials gave, or else
+        scans every tile once (``entries`` spans).  The registry counters
+        ``checksum.device`` and ``checksum.host`` count the campaigns of
+        each kind."""
         tracer = obs.get_tracer()
         i0 = tracer.event_count() if tracer is not None else 0
         jit0 = jit_counts()
@@ -157,8 +162,11 @@ class SimilarityEngine:
             result = run(*args)
         with obs.span("count"):
             comparisons = _campaign_comparisons(result)
+        source = getattr(result, "checksum_source", "host")
+        default_registry().counter(f"checksum.{source}").inc(
+            len(getattr(result, "campaigns", (result,))))
         result.meta["obs"] = _obs_block(
-            comparisons, time.perf_counter() - t0, tracer, i0, jit0,
+            comparisons, time.perf_counter() - t0, tracer, i0, jit0, source,
         )
         return result
 

@@ -73,6 +73,23 @@ class SimilarityResult:
     _checksum: int = field(default=None, init=False, repr=False, compare=False)
     _num_results: int = field(default=None, init=False, repr=False, compare=False)
 
+    def __post_init__(self):
+        # outputs that still had their blocks on the device carry the
+        # folded device partials: checksum() and num_results() read them
+        if self.checksum_source == "device":
+            raws = [o.device_raw for o in self.outputs]
+            self._checksum = ck.combine(raws)
+            self._num_results = sum(count for _, count in raws)
+
+    @property
+    def checksum_source(self) -> str:
+        """"device" when every output carries its device checksum partials
+        (``device_raw``), else "host": ``checksum()`` scans the tiles."""
+        if self.outputs and all(o.device_raw is not None
+                                for o in self.outputs):
+            return "device"
+        return "host"
+
     # -- streaming reads ---------------------------------------------------
 
     def tiles(self):
@@ -107,8 +124,10 @@ class SimilarityResult:
     def checksum(self) -> int:
         """Paper §5 exact campaign checksum (all stages combined).
 
-        Each tile's hash is a ``hash`` span; the tiles' index assembly
-        shows as the engines' ``entries`` spans."""
+        Folded from the device partials where the engine computed them
+        (``checksum_source``); otherwise each tile's hash is a ``hash``
+        span, and the tiles' index assembly shows as the engines'
+        ``entries`` spans."""
         if self._checksum is None:
             parts = []
             count = 0
@@ -218,4 +237,6 @@ class SimilarityResult:
                 f"checksum mismatch loading {path}: manifest {m['checksum']}, "
                 f"recomputed {got}"
             )
+        if "obs" in result.meta:  # recomputed here, over the stored tiles
+            result.meta["obs"]["checksum"] = result.checksum_source
         return result

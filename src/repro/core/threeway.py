@@ -59,7 +59,12 @@ from repro.core.metric_spec import (
 )
 from repro.core.plan3 import ItemKind, ThreeWayPlan, PERMS
 from repro.core.tile_executor import TileExecutor
-from repro.core.twoway import CometConfig, _run_program, batch_accounting
+from repro.core.twoway import (
+    CometConfig,
+    _run_program,
+    batch_accounting,
+    checksum_launcher,
+)
 from repro.obs import trace as obs
 
 __all__ = [
@@ -419,6 +424,9 @@ class ThreeWayOutput:
     n_v: int
     n_vp: int
     stage: int
+    #: (raw checksum total, result count) folded from the device partials
+    #: of the blocks (``ck.partials_program``), or None: read on the host
+    device_raw: tuple = None
 
     def entries(self):
         """Yield (i, j, k, value) for every unique computed triple.
@@ -442,27 +450,19 @@ class ThreeWayOutput:
         """(i, j, k, value) arrays of one computed item, or None when it
         holds no triple below ``n_v``."""
         m = self.n_vp
-        own, bj, bk = it.blocks(p_v, self.plan.n_pv)
+        pipe_b, left_b, right_b = _item_roles(it, p_v, self.plan.n_pv)
         lo, _ = self.plan.sixth_bounds(m, it.slice_idx, self.stage)
         jg = lo + np.arange(L)
         vals = self.blocks[p_v, p_r, slot]  # (L, m, m)
         if it.kind == ItemKind.DIAG:
-            pipe_b = left_b = right_b = own
             mask = (li[None, :, None] < jg[:, None, None]) & (
                 li[None, None, :] > jg[:, None, None]
             )
         elif it.kind == ItemKind.FACE:
-            pipe_b, left_b, right_b = bj, own, bj
             mask = np.broadcast_to(
                 li[None, None, :] > jg[:, None, None], vals.shape
             )
         else:
-            if it.slice_axis == 0:
-                pipe_b, left_b, right_b = own, bj, bk
-            elif it.slice_axis == 1:
-                pipe_b, left_b, right_b = bj, own, bk
-            else:
-                pipe_b, left_b, right_b = bk, own, bj
             mask = np.ones(vals.shape, bool)
         T, Ll, R = np.meshgrid(jg, li, li, indexing="ij")
         gi = pipe_b * m + T
@@ -485,6 +485,38 @@ class ThreeWayOutput:
 
     def num_triples(self) -> int:
         return sum(len(I) for I, _, _, _ in self.entries())
+
+
+def _item_roles(it, p_v: int, n_pv: int) -> tuple[int, int, int]:
+    """(pipe, left, right) block ids of an item: the blocks its (L, m, m)
+    slot's pipeline, row and column axes index."""
+    own, bj, bk = it.blocks(p_v, n_pv)
+    if it.kind == ItemKind.DIAG:
+        return own, own, own
+    if it.kind == ItemKind.FACE:
+        return bj, own, bj
+    return ((own, bj, bk), (bj, own, bk), (bk, own, bj))[it.slice_axis]
+
+
+def checksum_slots(plan: ThreeWayPlan, n_vp: int, n_v: int,
+                   stage: int) -> np.ndarray:
+    """(n_pv, n_pr, slots, 8) uint32 descriptors of one stage's output
+    slots for the device checksum (``ck.partials_program``): the pipe,
+    left and right block offsets, the sixth's start, whether rows must lie
+    below the pipe index (DIAG) and columns above it (DIAG, FACE), whether
+    the slot was computed, and ``n_v``; the masks of
+    ``ThreeWayOutput._item_entries``."""
+    desc = np.zeros((plan.n_pv, plan.n_pr, plan.slots_per_rank, 8),
+                    np.uint32)
+    for p_v in range(plan.n_pv):
+        for p_r in range(plan.n_pr):
+            for slot, it in enumerate(plan.items_of(p_v, p_r)):
+                pipe, left, right = _item_roles(it, p_v, plan.n_pv)
+                lo, _ = plan.sixth_bounds(n_vp, it.slice_idx, stage)
+                desc[p_v, p_r, slot] = (
+                    pipe * n_vp, left * n_vp, right * n_vp, lo,
+                    it.kind == ItemKind.DIAG, it.kind != ItemKind.VOL, 1, n_v)
+    return desc
 
 
 def _prep_payload3(V, cfg: CometConfig, metric: MetricSpec):
@@ -565,12 +597,15 @@ def threeway_distributed(
         check_vma=False,
     )
     L = n_vp // (6 * cfg.n_st)
-    blocks = _run_program(
+    with obs.span("entries"):
+        slots = checksum_slots(plan, n_vp, n_v, stage)
+    blocks, raw = _run_program(
         jax.jit(fn), arg,
         (cfg.n_pv, cfg.n_pr, plan.slots_per_rank, L, n_vp, n_vp),
-        stage=int(stage),
+        checksum=checksum_launcher(3, mesh, slots), stage=int(stage),
     )
-    return ThreeWayOutput(blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp, stage=stage)
+    return ThreeWayOutput(blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp,
+                          stage=stage, device_raw=raw)
 
 
 def threeway_batched(
@@ -601,7 +636,7 @@ def threeway_batched(
         check_vma=False,
     )
     L = n_vp // (6 * cfg.n_st)
-    blocks = _run_program(
+    blocks, _ = _run_program(
         jax.jit(fn), arg,
         (cfg.n_pv, cfg.n_pr, plan.slots_per_rank, len(flat), L, n_vp, n_vp),
         stage=int(stage), metrics=len(flat),

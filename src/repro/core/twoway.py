@@ -281,6 +281,9 @@ class TwoWayOutput:
     n_v: int  # true (unpadded) vector count
     n_vp: int  # padded block size
     storage: str = "dense"  # "dense" | "packed"
+    #: (raw checksum total, result count) folded from the device partials
+    #: of the blocks (``ck.partials_program``), or None: read on the host
+    device_raw: tuple = None
 
     # -- packed layout (deterministic from the plan) -----------------------
 
@@ -331,7 +334,7 @@ class TwoWayOutput:
                     )
         return TwoWayOutput(
             blocks=packed, plan=self.plan, n_v=self.n_v, n_vp=self.n_vp,
-            storage="packed",
+            storage="packed", device_raw=self.device_raw,
         )
 
     @property
@@ -374,6 +377,23 @@ class TwoWayOutput:
 
     def num_pairs(self) -> int:
         return sum(len(I) for I, _, _ in self.entries())
+
+
+def checksum_slots(plan: TwoWayPlan, n_vp: int, n_v: int) -> np.ndarray:
+    """(n_pv, n_pr, slots, 5) uint32 descriptors of the dense output slots
+    for the device checksum (``ck.partials_program``): the block's row and
+    column offsets, whether it is diagonal, whether the slot was computed,
+    and ``n_v``; the masks of ``global_pairs_of_block`` and ``entries``."""
+    desc = np.zeros((plan.n_pv, plan.n_pr, plan.slots_per_rank, 5),
+                    np.uint32)
+    for p_v in range(plan.n_pv):
+        for p_r in range(plan.n_pr):
+            for d in plan.steps_of_pr(p_r):
+                if plan.rank_computes(p_v, p_r, d):
+                    row, col = plan.block_of(p_v, d)
+                    desc[p_v, p_r, d // plan.n_pr] = (
+                        row * n_vp, col * n_vp, row == col, 1, n_v)
+    return desc
 
 
 #: Compiled-program cache for the 2-way shard_map programs.  ``jax.jit``
@@ -495,22 +515,51 @@ def _twoway_deferred_program(
     return out[None, None], s_own[None]
 
 
-def _run_program(fn, arg, shape, **attrs) -> np.ndarray:
+def checksum_launcher(way: int, mesh: Mesh, slots: np.ndarray):
+    """``out -> device partials``: dispatches the device checksum program
+    (``ck.partials_program``, one executable per output geometry) on a
+    campaign's output blocks; None where their value dtype has no device
+    path (``ck.device_dtype``)."""
+    def launch(out):
+        if not ck.device_dtype(out.dtype):
+            return None
+        fn = _cached_jit(
+            ("checksum", way, mesh, out.shape, str(out.dtype), slots.shape),
+            lambda: ck.partials_program(way, mesh),
+        )
+        return fn(out, slots)
+    return launch
+
+
+def _run_program(fn, arg, shape, checksum=None, **attrs):
     """Run a jitted campaign program on its staged payload and read its
-    blocks back into a host array of ``shape``.
+    blocks back into a host array of ``shape``.  Returns ``(blocks, raw)``:
+    ``raw`` is the ``(raw checksum total, result count)`` folded from the
+    device partials that ``checksum`` (a ``checksum_launcher``) dispatches,
+    or None.
 
     Three spans split the time: ``dispatch`` (the call until it returns:
     trace, lowering, compile or cache load, enqueue), ``ring-step`` (the
     wait for the device program) and ``readback`` (the copy to the host).
     The wait costs nothing extra: the readback right after it would
-    block until the program is done anyway."""
+    block until the program is done anyway.  The checksum partials are
+    dispatched before the wait, so they run during the copy; their launch,
+    and their wait and fold after the readback, are ``hash`` spans."""
     with obs.span("dispatch"):
         out = fn(arg)
+    parts = None
+    if checksum is not None:
+        with obs.span("hash"):
+            parts = checksum(out)
     with obs.span("ring-step") as sp:
         jax.block_until_ready(out)
         sp.add(payload_bytes=int(arg.nbytes), **attrs)
     with obs.span("readback"):
-        return np.asarray(out).reshape(shape)
+        blocks = np.asarray(out).reshape(shape)
+    if parts is None:
+        return blocks, None
+    with obs.span("hash"):
+        return blocks, ck.fold_partials(parts)
 
 
 def _prep_payload(V, cfg: CometConfig, metric: MetricSpec):
@@ -581,11 +630,14 @@ def twoway_distributed(
             check_vma=False,
         ),
     )
-    blocks = _run_program(
+    with obs.span("entries"):
+        slots = checksum_slots(plan, n_vp, n_v)
+    blocks, raw = _run_program(
         fn, arg, (cfg.n_pv, cfg.n_pr, plan.slots_per_rank, n_vp, n_vp),
-        steps=int(plan.n_steps),
+        checksum=checksum_launcher(2, mesh, slots), steps=int(plan.n_steps),
     )
-    return TwoWayOutput(blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp)
+    return TwoWayOutput(blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp,
+                        device_raw=raw)
 
 
 def _twoway_batched_program(
@@ -726,7 +778,7 @@ def twoway_batched(
         out_specs=P("pv", "pr", None, None, None, None),
         check_vma=False,
     )
-    blocks = _run_program(
+    blocks, _ = _run_program(
         jax.jit(fn), arg,
         (cfg.n_pv, cfg.n_pr, len(flat), plan.slots_per_rank, n_vp, n_vp),
         steps=int(plan.n_steps), metrics=len(flat),

@@ -56,6 +56,9 @@ def check_2way(V, ref_dense):
         if ref_checksum is None:
             ref_checksum = c
         assert c == ref_checksum, f"2way checksum mismatch for {cfg}"
+        # the device partials fold to the host scan's checksum and count
+        assert out.device_raw[1] == out.num_pairs(), f"2way {cfg} count"
+        assert ck.combine([out.device_raw]) == c, f"2way {cfg} device"
         print(f"  2way pf={n_pf} pv={n_pv} pr={n_pr}: OK ({hex(c)[:14]})")
     # pallas fused-epilogue path inside the distributed engine (interpret
     # mode): in-kernel assembly + triangular diagonal-block schedule must be
@@ -122,6 +125,8 @@ def check_3way(V, ref_dense):
         if ref_checksum is None:
             ref_checksum = c
         assert c == ref_checksum, f"3way checksum mismatch for {cfg}"
+        assert out.device_raw[1] == n_unique, f"3way {cfg} count"
+        assert ck.combine([out.device_raw]) == c, f"3way {cfg} device"
         print(f"  3way pf={n_pf} pv={n_pv} pr={n_pr}: OK ({hex(c)[:14]})")
 
     # pallas path: fused X_j pipeline-step kernels, bit-identical numerators
