@@ -56,6 +56,12 @@ class BatchedSimilarityResult:
                 return r
         raise KeyError(f"no campaign (metric={metric!r}, subset={subset!r})")
 
+    @property
+    def path(self):
+        """The contraction path every campaign shares, else None."""
+        paths = {r.path for _m, _s, r in self.campaigns}
+        return paths.pop() if len(paths) == 1 else None
+
     def checksums(self) -> dict:
         """{(metric, subset_name): checksum} over every campaign."""
         return {(m, s): r.checksum() for m, s, r in self.campaigns}
@@ -90,6 +96,7 @@ def extract_twoway(full: TwoWayOutput, pos) -> TwoWayOutput:
         sub[lo, hi] = v
     return TwoWayOutput(
         blocks=sub[None, None, None], plan=TwoWayPlan(1, 1), n_v=m, n_vp=m,
+        path=full.path,
     )
 
 
@@ -119,4 +126,5 @@ def extract_threeway(stage_outs, pos) -> ThreeWayOutput:
             blocks[0, 0, s, b - s * L, a, c] = V[keep]
     return ThreeWayOutput(
         blocks=blocks, plan=ThreeWayPlan(1, 1, 1), n_v=m, n_vp=mp, stage=0,
+        path=stage_outs[0].path,
     )

@@ -48,16 +48,18 @@ def _campaign_comparisons(result) -> int:
     return int(result.num_results()) * int(result.n_f)
 
 
-def _obs_block(comparisons, seconds, tracer, i0, jit0, checksum) -> dict:
+def _obs_block(comparisons, seconds, tracer, i0, jit0, checksum,
+               path) -> dict:
     """The normalized ``meta["obs"]`` block every campaign result carries.
 
     Always: achieved ``comparisons``, wall ``seconds``,
     ``comparisons_per_s``, ``jit``, the JAX lowerings and compiles
     counted in this process since ``jit0`` was read (a campaign that
     reuses its compiled program reads 0 and 0), and ``checksum``, where
-    the result's checksum and count come from ("device" or "host").  When
-    tracing was enabled for the run, also the per-phase breakdown from the
-    span events recorded since index ``i0``."""
+    the result's checksum and count come from ("device" or "host"), and
+    ``path``, the contraction path the executor resolved.  When tracing
+    was enabled for the run, also the per-phase breakdown from the span
+    events recorded since index ``i0``."""
     jit = jit_counts()
     block = {
         "comparisons": int(comparisons),
@@ -65,6 +67,7 @@ def _obs_block(comparisons, seconds, tracer, i0, jit0, checksum) -> dict:
         "comparisons_per_s": float(comparisons) / max(float(seconds), 1e-12),
         "jit": {k: jit[k] - jit0[k] for k in jit},
         "checksum": checksum,
+        "path": path,
     }
     if tracer is None:
         return block
@@ -153,7 +156,7 @@ class SimilarityEngine:
         ``count`` span) reads the count the device partials gave, or else
         scans every tile once (``entries`` spans).  The registry counters
         ``checksum.device`` and ``checksum.host`` count the campaigns of
-        each kind."""
+        each kind, and ``path.<path>`` those of each contraction path."""
         tracer = obs.get_tracer()
         i0 = tracer.event_count() if tracer is not None else 0
         jit0 = jit_counts()
@@ -163,10 +166,15 @@ class SimilarityEngine:
         with obs.span("count"):
             comparisons = _campaign_comparisons(result)
         source = getattr(result, "checksum_source", "host")
-        default_registry().counter(f"checksum.{source}").inc(
-            len(getattr(result, "campaigns", (result,))))
+        runs = [r for _m, _s, r in getattr(result, "campaigns", ())] \
+            or [result]
+        registry = default_registry()
+        registry.counter(f"checksum.{source}").inc(len(runs))
+        for r in runs:
+            registry.counter(f"path.{r.path}").inc()
         result.meta["obs"] = _obs_block(
             comparisons, time.perf_counter() - t0, tracer, i0, jit0, source,
+            result.path,
         )
         return result
 
@@ -301,6 +309,7 @@ class SimilarityEngine:
 
     def _run_delta(self, request, prior, V=None) -> SimilarityResult:
         from repro.core.delta import merge_delta, twoway_delta
+        from repro.core.tile_executor import TileExecutor
         from repro.kernels.mgemm_levels.planes import PackedPlanes
         from repro.store.reader import ShardedPlanes
 
@@ -383,7 +392,9 @@ class SimilarityEngine:
         if dinfo is None:
             rect, tri, rcfg, dinfo = twoway_delta(V, n_old, mesh, cfg, spec)
         out = merge_delta(
-            prior.outputs[0], rect, tri, n_old, m, rcfg.out_dtype
+            prior.outputs[0], rect, tri, n_old, m, rcfg.out_dtype,
+            path=TileExecutor(cfg=rcfg, metric=spec,
+                              deferred="stream" in meta).path,
         )
         seconds = time.perf_counter() - t0
         dinfo["prior"] = {"n_v": n_old, "checksum": hex(prior.checksum())}

@@ -90,6 +90,14 @@ class SimilarityResult:
             return "device"
         return "host"
 
+    @property
+    def path(self):
+        """The contraction path the campaign's ``TileExecutor`` resolved
+        (``TileExecutor.path`` 2-way, ``path3`` 3-way), shared by every
+        output; None where an output records none (``load()``)."""
+        paths = {o.path for o in self.outputs}
+        return paths.pop() if len(paths) == 1 else None
+
     # -- streaming reads ---------------------------------------------------
 
     def tiles(self):

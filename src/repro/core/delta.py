@@ -272,7 +272,7 @@ def twoway_delta(
 
 def merge_delta(
     prior: TwoWayOutput, rect: np.ndarray, tri: np.ndarray,
-    n_old: int, n_new: int, out_dtype,
+    n_old: int, n_new: int, out_dtype, path: str = None,
 ) -> TwoWayOutput:
     """Merge a prior result and its border blocks into packed storage.
 
@@ -281,7 +281,9 @@ def merge_delta(
     output, so deltas chain across appends).  The merged output is a
     single-rank ``TwoWayPlan(1, 1)`` packed upper triangle over
     ``N = n_old + n_new`` vectors whose entries — and therefore checksum —
-    are bit-identical to a full recompute."""
+    are bit-identical to a full recompute.  ``path`` is the border
+    blocks' contraction path (``TileExecutor.path``), recorded on the
+    merged output."""
     if prior.n_v != n_old:
         raise ValueError(
             f"prior covers n_v={prior.n_v} vectors, delta says n_old={n_old}"
@@ -303,4 +305,5 @@ def merge_delta(
         sp.add(entries=int(buf.size), n_old=int(n_old), n_new=int(n_new))
     return TwoWayOutput(
         blocks=flat, plan=TwoWayPlan(1, 1), n_v=N, n_vp=N, storage="packed",
+        path=path,
     )

@@ -427,6 +427,10 @@ class ThreeWayOutput:
     #: (raw checksum total, result count) folded from the device partials
     #: of the blocks (``ck.partials_program``), or None: read on the host
     device_raw: tuple = None
+    #: the contraction path the campaign's ``TileExecutor`` resolved
+    #: (``TileExecutor.path3``, e.g. "fused-levels-ring"), or None where
+    #: no campaign produced the blocks (``load()``)
+    path: str = None
 
     def entries(self):
         """Yield (i, j, k, value) for every unique computed triple.
@@ -605,7 +609,8 @@ def threeway_distributed(
         checksum=checksum_launcher(3, mesh, slots), stage=int(stage),
     )
     return ThreeWayOutput(blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp,
-                          stage=stage, device_raw=raw)
+                          stage=stage, device_raw=raw,
+                          path=TileExecutor(cfg=cfg, metric=metric).path3)
 
 
 def threeway_batched(
@@ -641,10 +646,13 @@ def threeway_batched(
         (cfg.n_pv, cfg.n_pr, plan.slots_per_rank, len(flat), L, n_vp, n_vp),
         stage=int(stage), metrics=len(flat),
     )
+    # every member rides its family lead's slice contraction
+    paths = {s.name: TileExecutor(cfg=cfg, metric=grp[0]).path3
+             for grp in groups for s in grp}
     by_name = {
         s.name: ThreeWayOutput(
             blocks=np.ascontiguousarray(blocks[:, :, :, i]), plan=plan,
-            n_v=n_v, n_vp=n_vp, stage=stage,
+            n_v=n_v, n_vp=n_vp, stage=stage, path=paths[s.name],
         )
         for i, s in enumerate(flat)
     }

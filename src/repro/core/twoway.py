@@ -284,6 +284,10 @@ class TwoWayOutput:
     #: (raw checksum total, result count) folded from the device partials
     #: of the blocks (``ck.partials_program``), or None: read on the host
     device_raw: tuple = None
+    #: the contraction path the campaign's ``TileExecutor`` resolved
+    #: (``TileExecutor.path``, e.g. "fused-popcount"), or None where no
+    #: campaign produced the blocks (``load()``)
+    path: str = None
 
     # -- packed layout (deterministic from the plan) -----------------------
 
@@ -334,7 +338,7 @@ class TwoWayOutput:
                     )
         return TwoWayOutput(
             blocks=packed, plan=self.plan, n_v=self.n_v, n_vp=self.n_vp,
-            storage="packed", device_raw=self.device_raw,
+            storage="packed", device_raw=self.device_raw, path=self.path,
         )
 
     @property
@@ -637,7 +641,8 @@ def twoway_distributed(
         checksum=checksum_launcher(2, mesh, slots), steps=int(plan.n_steps),
     )
     return TwoWayOutput(blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp,
-                        device_raw=raw)
+                        device_raw=raw,
+                        path=TileExecutor(cfg=cfg, metric=metric).path)
 
 
 def _twoway_batched_program(
@@ -783,10 +788,13 @@ def twoway_batched(
         (cfg.n_pv, cfg.n_pr, len(flat), plan.slots_per_rank, n_vp, n_vp),
         steps=int(plan.n_steps), metrics=len(flat),
     )
+    # every member rides its family lead's contraction (``pair_raw``)
+    paths = {s.name: TileExecutor(cfg=cfg, metric=grp[0]).path
+             for grp in groups for s in grp}
     by_name = {
         s.name: TwoWayOutput(
             blocks=np.ascontiguousarray(blocks[:, :, i]), plan=plan,
-            n_v=n_v, n_vp=n_vp,
+            n_v=n_v, n_vp=n_vp, path=paths[s.name],
         )
         for i, s in enumerate(flat)
     }

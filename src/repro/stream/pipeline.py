@@ -207,7 +207,8 @@ def stream_twoway(
     with obs.span("merge") as sp:
         blocks = _merge_twoway_blocks(cfg, plan, executor, acc, stats)
         sp.add(blocks=int(blocks.size))
-    out = TwoWayOutput(blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp)
+    out = TwoWayOutput(blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp,
+                       path=executor.path)
     info = _stream_info(splan, cfg, sh.n_shards)
     info["staged_bytes"] = staged
     info.update(overlap)
@@ -321,7 +322,7 @@ def stream_threeway(
         )
         sp.add(blocks=int(blocks.size))
     out = ThreeWayOutput(blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp,
-                         stage=stage)
+                         stage=stage, path=executor.path3)
     info = _stream_info(splan, cfg, sh.n_shards)
     info["staged_bytes"] = staged
     info.update(overlap)
@@ -504,7 +505,8 @@ def stream_twoway_batched(dataset, mesh, cfg: CometConfig, specs) -> tuple:
                 cfg, plan, executor, acc[:, :, g], stats[:, g]
             )
             by_name[s.name] = TwoWayOutput(
-                blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp
+                blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp,
+                path=executor.path,
             )
         sp.add(metrics=len(flat))
     info = _stream_info(splan, cfg, sh.n_shards)
@@ -581,7 +583,8 @@ def stream_threeway_batched(
                 [a[:, :, :, g] for a in accs], stats[:, g], L, n_vp,
             )
             by_name[s.name] = ThreeWayOutput(
-                blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp, stage=stage
+                blocks=blocks, plan=plan, n_v=n_v, n_vp=n_vp, stage=stage,
+                path=executor.path3,
             )
         sp.add(metrics=len(flat))
     info = _stream_info(splan, cfg, sh.n_shards)

@@ -503,3 +503,58 @@ def test_campaign_jit_counts(way):
         assert again == {"lowerings": 0, "compiles": 0}
     else:
         assert again["lowerings"] >= 1 and again["compiles"] >= 1
+
+
+@pytest.mark.parametrize("metric,levels,path", [
+    ("sorenson", 1, "fused-popcount"),
+    ("czekanowski", 2, "fused-levels"),
+])
+def test_campaign_path_counter(metric, levels, path):
+    """Each campaign counts the contraction path its ``TileExecutor``
+    resolved as ``path.<path>`` in the default registry, once, and
+    records it in ``meta["obs"]["path"]`` beside the checksum source."""
+    from repro.obs.metrics import default_registry
+
+    reg = default_registry()
+    V = random_integer_vectors(48, 24, max_value=levels, seed=6)
+    before = {k: v for k, v in reg.snapshot().items()
+              if k.startswith("path.")}
+    result = SimilarityEngine().run(SimilarityRequest(
+        way=2, metric=metric, impl="levels", levels=levels), V)
+    after = {k: v for k, v in reg.snapshot().items() if k.startswith("path.")}
+    counted = {k: after[k] - before.get(k, 0) for k in after}
+    assert {k: n for k, n in counted.items() if n} == {f"path.{path}": 1}
+    assert result.path == path
+    assert result.meta["obs"]["path"] == path
+    assert result.meta["obs"]["checksum"] == "device"
+
+
+def test_campaign_path_on_every_engine_path(tmp_path):
+    """3-way, batched, streamed and delta campaigns record their path too;
+    a loaded result keeps the path its campaign recorded in ``meta``."""
+    engine = SimilarityEngine()
+    V = random_integer_vectors(32, 14, max_value=1, seed=3)
+    req = dict(way=2, metric="sorenson", impl="levels", levels=1)
+    threeway = engine.run(SimilarityRequest(
+        way=3, metric="sorenson", impl="levels", levels=1, n_st=2,
+        encoding="bitplane"), V)
+    assert threeway.meta["obs"]["path"] == "fused-popcount-ring"
+    batched = engine.run(SimilarityRequest(
+        **req, metrics=("czekanowski",)), V)
+    assert batched.meta["obs"]["path"] == "fused-popcount"
+    assert all(r.path == "fused-popcount" for _, _, r in batched)
+
+    path = os.path.join(str(tmp_path), "ds")
+    write_dataset(path, V, levels=1, n_shards=2)
+    sreq = SimilarityRequest(**req, streaming="on", max_host_bytes=400,
+                             input=InputSpec(source="planes", path=path))
+    streamed = engine.run(sreq)
+    assert streamed.meta["obs"]["path"] == "streamed-fused-popcount"
+    saved = os.path.join(str(tmp_path), "saved")
+    streamed.save(saved)
+    loaded = type(streamed).load(saved)
+    assert loaded.path is None
+    assert loaded.meta["obs"]["path"] == "streamed-fused-popcount"
+    append_dataset(path, random_integer_vectors(32, 4, max_value=1, seed=4))
+    delta = engine.run_delta(sreq, streamed)
+    assert delta.meta["obs"]["path"] == "streamed-fused-popcount"
