@@ -61,6 +61,7 @@ from repro.core.plan3 import ItemKind, ThreeWayPlan, PERMS
 from repro.core.tile_executor import TileExecutor
 from repro.core.twoway import (
     CometConfig,
+    _cached_jit,
     _run_program,
     batch_accounting,
     checksum_launcher,
@@ -592,19 +593,23 @@ def threeway_distributed(
     plan = ThreeWayPlan(cfg.n_pv, cfg.n_pr, cfg.n_st)
     out_dtype = jnp.dtype(cfg.out_dtype)
 
-    fn = jax.shard_map(
-        partial(_threeway_program, cfg=cfg, plan=plan, stage=stage,
-                out_dtype=out_dtype, metric=metric),
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=P("pv", "pr", None, None, None, None),
-        check_vma=False,
+    fn = _cached_jit(
+        ("threeway", mesh, cfg, plan, stage, metric.name, str(out_dtype),
+         in_specs),
+        lambda: jax.shard_map(
+            partial(_threeway_program, cfg=cfg, plan=plan, stage=stage,
+                    out_dtype=out_dtype, metric=metric),
+            mesh=mesh,
+            in_specs=in_specs,
+            out_specs=P("pv", "pr", None, None, None, None),
+            check_vma=False,
+        ),
     )
     L = n_vp // (6 * cfg.n_st)
     with obs.span("entries"):
         slots = checksum_slots(plan, n_vp, n_v, stage)
     blocks, raw = _run_program(
-        jax.jit(fn), arg,
+        fn, arg,
         (cfg.n_pv, cfg.n_pr, plan.slots_per_rank, L, n_vp, n_vp),
         checksum=checksum_launcher(3, mesh, slots), stage=int(stage),
     )
@@ -632,17 +637,22 @@ def threeway_batched(
     plan = ThreeWayPlan(cfg.n_pv, cfg.n_pr, cfg.n_st)
     out_dtype = jnp.dtype(cfg.out_dtype)
 
-    fn = jax.shard_map(
-        partial(_threeway_program, cfg=cfg, plan=plan, stage=stage,
-                out_dtype=out_dtype, groups=groups),
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=P("pv", "pr", None, None, None, None, None),
-        check_vma=False,
+    names = tuple(tuple(s.name for s in grp) for grp in groups)
+    fn = _cached_jit(
+        ("threeway-batched", mesh, cfg, plan, stage, names, str(out_dtype),
+         in_specs),
+        lambda: jax.shard_map(
+            partial(_threeway_program, cfg=cfg, plan=plan, stage=stage,
+                    out_dtype=out_dtype, groups=groups),
+            mesh=mesh,
+            in_specs=in_specs,
+            out_specs=P("pv", "pr", None, None, None, None, None),
+            check_vma=False,
+        ),
     )
     L = n_vp // (6 * cfg.n_st)
     blocks, _ = _run_program(
-        jax.jit(fn), arg,
+        fn, arg,
         (cfg.n_pv, cfg.n_pr, plan.slots_per_rank, len(flat), L, n_vp, n_vp),
         stage=int(stage), metrics=len(flat),
     )

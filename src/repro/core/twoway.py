@@ -37,6 +37,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import checksum as ck
 from repro.obs import trace as obs
+from repro.obs.metrics import default_registry
 from repro.core.metric_spec import (
     CZEKANOWSKI,
     MetricSpec,
@@ -400,13 +401,21 @@ def checksum_slots(plan: TwoWayPlan, n_vp: int, n_v: int) -> np.ndarray:
     return desc
 
 
-#: Compiled-program cache for the 2-way shard_map programs.  ``jax.jit``
-#: memoizes per function object, and the entry points used to build a fresh
-#: ``partial`` (hence a fresh jit cache) per campaign — every repeated
-#: request paid trace+compile again.  Keying the jitted callable on
-#: (mesh, cfg, plan geometry, metric name, flags) lets a hot serving
-#: process — and ``SimilarityService.warmup`` — reuse the compiled
-#: executable across requests; jit still retraces on a shape change.
+#: Compiled-program cache for every campaign program (2-way, 3-way,
+#: batched, delta, device checksum).  ``jax.jit`` memoizes per function
+#: object, so a fresh ``partial`` per campaign would pay trace, lowering and
+#: compile (or a persistent-cache load) on every request.  Keying the jitted
+#: callable lets a hot process — and ``SimilarityService.warmup`` — reuse
+#: the executable across requests; jit still retraces on a shape change.
+#: A key must hold every value its ``build`` closure captures: mesh,
+#: resolved cfg, plan, metric name (per-family name tuples for a batch),
+#: output dtype, payload kind (``planes`` or its ``in_specs``) and, on
+#: 3-way, the ``stage`` the program bakes in.  ``_cached_jit`` adds the
+#: ring exchange in force, ``jax.lax.ppermute``: the programs look it up
+#: when traced, so a program traced with a stand-in (the benchmark's
+#: ``no_exchange`` fault scenario plants one) is never served with the
+#: real exchange, nor the other way round.  ``default_registry()`` counts
+#: the lookups as ``program_cache.hit`` / ``program_cache.miss``.
 _PROGRAM_CACHE: "OrderedDict" = None
 
 
@@ -417,12 +426,15 @@ def _cached_jit(key, build):
         from collections import OrderedDict
 
         _PROGRAM_CACHE = OrderedDict()
+    key += (jax.lax.ppermute,)
     fn = _PROGRAM_CACHE.get(key)
     if fn is None:
+        default_registry().counter("program_cache.miss").inc()
         fn = _PROGRAM_CACHE[key] = jax.jit(build())
         while len(_PROGRAM_CACHE) > 128:
             _PROGRAM_CACHE.popitem(last=False)
     else:
+        default_registry().counter("program_cache.hit").inc()
         _PROGRAM_CACHE.move_to_end(key)
     return fn
 
@@ -543,7 +555,8 @@ def _run_program(fn, arg, shape, checksum=None, **attrs):
     or None.
 
     Three spans split the time: ``dispatch`` (the call until it returns:
-    trace, lowering, compile or cache load, enqueue), ``ring-step`` (the
+    the enqueue, and on a program's first call or a new shape also trace,
+    lowering, compile or cache load), ``ring-step`` (the
     wait for the device program) and ``readback`` (the copy to the host).
     The wait costs nothing extra: the readback right after it would
     block until the program is done anyway.  The checksum partials are
@@ -775,16 +788,20 @@ def twoway_batched(
     plan = TwoWayPlan(cfg.n_pv, cfg.n_pr)
     out_dtype = jnp.dtype(cfg.out_dtype)
 
-    fn = jax.shard_map(
-        partial(_twoway_batched_program, cfg=cfg, plan=plan,
-                out_dtype=out_dtype, groups=groups, planes=planes),
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=P("pv", "pr", None, None, None, None),
-        check_vma=False,
+    names = tuple(tuple(s.name for s in grp) for grp in groups)
+    fn = _cached_jit(
+        ("twoway-batched", mesh, cfg, plan, names, str(out_dtype), planes),
+        lambda: jax.shard_map(
+            partial(_twoway_batched_program, cfg=cfg, plan=plan,
+                    out_dtype=out_dtype, groups=groups, planes=planes),
+            mesh=mesh,
+            in_specs=in_specs,
+            out_specs=P("pv", "pr", None, None, None, None),
+            check_vma=False,
+        ),
     )
     blocks, _ = _run_program(
-        jax.jit(fn), arg,
+        fn, arg,
         (cfg.n_pv, cfg.n_pr, len(flat), plan.slots_per_rank, n_vp, n_vp),
         steps=int(plan.n_steps), metrics=len(flat),
     )
