@@ -1,6 +1,6 @@
-"""The paper's technique inside the LM data pipeline: near-duplicate
-detection over documents via all-pairs Czekanowski similarity of token
-count-profiles (DESIGN.md §5).
+"""The paper's technique on a document corpus: near-duplicate detection
+over documents via all-pairs Czekanowski similarity of token
+count-profiles.
 
     PYTHONPATH=src python examples/dataset_dedup.py
 """

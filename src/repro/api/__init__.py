@@ -1,6 +1,6 @@
 """repro.api — the unified similarity API.
 
-One entry point for every similarity campaign (CLI, benchmarks, examples,
+One entry point for every similarity campaign (CLI, bench/, examples,
 serving): build a frozen ``SimilarityRequest``, hand it to a
 ``SimilarityEngine``, stream the ``SimilarityResult``.  New metrics plug in
 through ``register_metric`` without touching engine code.

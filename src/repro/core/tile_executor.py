@@ -82,7 +82,7 @@ class TileExecutor:
 
     ``axis`` is the mesh axis numerator partials are psummed over on the
     unfused path ("pf" inside the distributed programs); ``None`` outside
-    shard_map (single-process tests, benchmarks).
+    shard_map (single-process tests).
     """
 
     cfg: Any  # CometConfig (duck-typed to avoid a core.twoway import cycle)

@@ -1,1 +1,1 @@
-from repro.data.tokens import SyntheticTokens, PrefetchIterator  # noqa: F401
+from repro.data.dedup import find_near_duplicates  # noqa: F401

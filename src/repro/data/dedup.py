@@ -1,5 +1,5 @@
 """Near-duplicate detection over documents — the paper's technique applied
-to the LM data pipeline (DESIGN.md §5).
+to a document corpus.
 
 Documents are summarized as token count-profile vectors (the Proportional
 Similarity metric's native input: non-negative profiles); all-pairs 2-way

@@ -24,8 +24,8 @@ def threeway_step(own, x, right, *, combine, **kw):
     """Metric-generic fused 3-way pipeline step (X_j never touches HBM).
 
     own (n_f, m), x (n_f,) single pipeline column, right (n_f, n) ->
-    (m, n).  Single-column form kept for benchmarks/oracles; the executor
-    runs the batched variants below."""
+    (m, n).  Single-column form, called only by tests; the executor runs
+    the batched variants below."""
     kw.setdefault("interpret", interpret_mode())
     return threeway_step_pallas(own, x, right, combine=combine, **kw)
 

@@ -17,7 +17,7 @@ the MXU matmuls), so HBM traffic is identical to a plain GEMM of the raw
 operands.
 
 Cost: L * 2*M*N*K MXU FLOPs; for L <= 4 a ~25-50x win over the VPU kernel on
-the compute roofline term (see EXPERIMENTS.md §Perf).
+the compute roofline term.
 """
 from __future__ import annotations
 

@@ -24,8 +24,7 @@ def mgemm_levels(A, B, *, levels: int = 2, **kw):
 
 def mgemm_levels_xla(A, B, *, levels: int = 2, out_dtype=jnp.float32):
     """XLA (non-Pallas) realization — what the distributed engines call on
-    CPU, and what the dry-run lowers on the v5e mesh (plain dots partition
-    cleanly under GSPMD)."""
+    CPU (plain dots partition cleanly under GSPMD)."""
     acc = jnp.zeros((A.shape[0], B.shape[1]), jnp.float32)
     for t in range(1, levels + 1):
         at = (A >= t).astype(jnp.bfloat16)

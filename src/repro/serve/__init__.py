@@ -1,1 +1,1 @@
-from repro.serve.engine import ServeEngine, SimilarityService  # noqa: F401
+from repro.serve.engine import SimilarityService  # noqa: F401

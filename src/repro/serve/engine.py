@@ -1,18 +1,10 @@
-"""Serving engines.
+"""Similarity campaigns as a service.
 
-Two request families share this module:
-
-* ``ServeEngine`` — batched LM generation: prefill + greedy/temperature
-  decode with KV (or SSM-state) caches and per-sequence stopping.  The
-  decode loop is a single jit'd step over the full batch (static shapes);
-  finished sequences keep decoding into a scratch slot but their outputs
-  are frozen — the standard static-batch serving pattern.
-
-* ``SimilarityService`` — similarity campaigns as a service: frozen
-  ``SimilarityRequest``s go through the SAME ``repro.api.SimilarityEngine``
-  the CLI and benchmarks use (one code path to validate), with engine reuse
-  across requests sharing a device pool and an LRU result cache keyed by
-  (request, input fingerprint) so repeated campaigns are free.
+``SimilarityService`` sends frozen ``SimilarityRequest``s through the SAME
+``repro.api.SimilarityEngine`` the CLI and ``bench/`` use (one code path to
+validate), with engine reuse across requests sharing a device pool and an
+LRU result cache keyed by (request, input fingerprint) so repeated
+campaigns are free.
 """
 from __future__ import annotations
 
@@ -23,107 +15,18 @@ import threading
 import time
 from collections import OrderedDict
 from concurrent.futures import Future
-from dataclasses import dataclass, replace
+from dataclasses import replace
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.models import api
-from repro.models.common import ModelConfig
 from repro.obs import trace as obs
 from repro.obs.metrics import MetricsRegistry
-from repro.parallel.sharding import use_mesh
 
 #: Virtual Chrome-trace lane for queue-wait intervals: a wait often
 #: overlaps the worker thread's own spans (it began while the previous
 #: request was still computing), so it gets its own tid to keep per-lane
 #: B/E nesting well-formed.
 _QUEUE_LANE_TID = 0
-
-
-@dataclass
-class ServeConfig:
-    max_new_tokens: int = 32
-    temperature: float = 0.0  # 0 -> greedy
-    eos_id: int = 2
-    seed: int = 0
-
-
-class ServeEngine:
-    def __init__(self, cfg: ModelConfig, params, scfg: ServeConfig | None = None,
-                 mesh=None, registry: MetricsRegistry | None = None):
-        self.cfg = cfg
-        self.params = params
-        self.scfg = scfg or ServeConfig()
-        self.mesh = mesh
-        self.registry = registry if registry is not None else MetricsRegistry()
-        self._decode = jax.jit(
-            lambda p, c, t, i: api.decode_step(cfg, p, c, t, i)
-        )
-
-    def _prefill(self, tokens):
-        """Feed the prompt one block at a time through decode steps.
-
-        For attention archs this fills the KV cache; a production prefill
-        would batch the whole prompt (see launch/dryrun.py's prefill_step —
-        the serving engine here favors simplicity on CPU)."""
-        B, P = tokens.shape
-        cache = api.init_cache(
-            self.cfg, self.params, B, P + self.scfg.max_new_tokens
-        )
-        logits = None
-        for i in range(P):
-            logits, cache = self._decode(
-                self.params, cache, tokens[:, i : i + 1], i
-            )
-        return logits, cache, P
-
-    def generate(self, prompts: np.ndarray) -> np.ndarray:
-        """prompts (B, P) int32 -> (B, max_new_tokens) int32.
-
-        Records per-request metrics in ``self.registry``: ``requests`` /
-        ``tokens_generated`` counters and ``prefill_seconds`` /
-        ``decode_step_seconds`` latency histograms (``serve
-        --metrics-json`` dumps the snapshot)."""
-        scfg = self.scfg
-        reg = self.registry
-        with use_mesh(self.mesh):
-            t0 = time.perf_counter()
-            logits, cache, pos = self._prefill(jnp.asarray(prompts))
-            B = prompts.shape[0]
-            out = np.zeros((B, scfg.max_new_tokens), np.int32)
-            done = np.zeros((B,), bool)
-            key = jax.random.PRNGKey(scfg.seed)
-            tok = self._sample(logits, key)
-            reg.histogram("prefill_seconds").observe(time.perf_counter() - t0)
-            steps = 0
-            for t in range(scfg.max_new_tokens):
-                ts = time.perf_counter()
-                out[:, t] = np.where(done, 0, np.asarray(tok[:, 0]))
-                done |= np.asarray(tok[:, 0]) == scfg.eos_id
-                steps += 1
-                if done.all():
-                    reg.histogram("decode_step_seconds").observe(
-                        time.perf_counter() - ts
-                    )
-                    break
-                logits, cache = self._decode(self.params, cache, tok, pos + t)
-                key, sub = jax.random.split(key)
-                tok = self._sample(logits, sub)
-                reg.histogram("decode_step_seconds").observe(
-                    time.perf_counter() - ts
-                )
-        with reg.locked():
-            reg.counter("requests").inc()
-            reg.counter("tokens_generated").inc(B * steps)
-        return out
-
-    def _sample(self, logits, key):
-        if self.scfg.temperature <= 0:
-            return jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        scaled = logits[:, -1, :] / self.scfg.temperature
-        return jax.random.categorical(key, scaled)[:, None].astype(jnp.int32)
 
 
 def _payload_hash(V) -> str:
@@ -152,7 +55,7 @@ class SimilarityService:
     """Similarity campaigns behind an async serving front-end.
 
     Every request is executed by ``repro.api.SimilarityEngine`` — the exact
-    code path of the CLI and benchmarks — so serving never drifts from the
+    code path of the CLI and ``bench/`` — so serving never drifts from the
     validated engines.  ``submit_async`` enqueues the campaign to a worker
     thread pool and returns a ``concurrent.futures.Future``; ``submit`` is
     the blocking wrapper.  Results are LRU-cached by (normalized request,

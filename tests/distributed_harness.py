@@ -8,7 +8,13 @@ parallel decompositions (n_pf, n_pv, n_pr, n_st), and asserts
      inputs => exact numerators => identical IEEE divisions),
   3. values match the O(n^2)/O(n^3) numpy oracles.
 
-Invoked by tests/test_distributed.py; standalone: python distributed_harness.py
+The cases are the table in tests/distributed_cases.py; each names one
+``check_*`` function below and its arguments.  Every case runs in this one
+process (the device count is fixed before jax starts) and writes one JSON
+record ``{"id", "ok", "error", "seconds"}``, so a fault in one decomposition
+fails that case alone.  Invoked by tests/test_distributed.py; standalone:
+
+    PYTHONPATH=src python tests/distributed_harness.py [--out records.jsonl]
 """
 import os
 
@@ -16,8 +22,16 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 " + os.environ.get("XLA_FLAGS", "")
 )
 
+import argparse  # noqa: E402
+import functools  # noqa: E402
+import json  # noqa: E402
+import tempfile  # noqa: E402
+import time  # noqa: E402
+import traceback  # noqa: E402
+
 import numpy as np  # noqa: E402
 
+from distributed_cases import CASES  # noqa: E402
 from repro.core.metrics import czek2_metric_np, czek3_metric_np  # noqa: E402
 from repro.core.synthetic import random_integer_vectors  # noqa: E402
 from repro.core.threeway import czek3_distributed  # noqa: E402
@@ -26,477 +40,435 @@ from repro.core import checksum as ck  # noqa: E402
 from repro.parallel.mesh import make_comet_mesh  # noqa: E402
 
 N_F, N_V = 24, 24
+#: the delta cases split V's columns into n_old | N_V - n_old
+N_OLD = 15
+#: the datasets the store, streamed and delta cases read, made once
+_TMP = tempfile.TemporaryDirectory()
 
 
-def check_2way(V, ref_dense):
-    ref_checksum = None
-    configs = [
-        (1, 1, 1),
-        (1, 2, 1),
-        (1, 4, 1),
-        (1, 8, 1),
-        (2, 2, 1),
-        (1, 2, 2),
-        (2, 2, 2),
-        (1, 4, 2),
-        (4, 2, 1),
-    ]
-    for n_pf, n_pv, n_pr in configs:
-        cfg = CometConfig(n_pf=n_pf, n_pv=n_pv, n_pr=n_pr)
-        mesh = make_comet_mesh(n_pf, n_pv, n_pr)
-        out = czek2_distributed(V, mesh, cfg)
+@functools.cache
+def _V():
+    return random_integer_vectors(N_F, N_V, max_value=15, seed=42)
+
+
+@functools.cache
+def _Vb():
+    """Binary ({0,1}) data for the popcount cases."""
+    return random_integer_vectors(N_F, N_V, max_value=1, seed=43)
+
+
+@functools.cache
+def _oracle(way):
+    fn = czek2_metric_np if way == 2 else czek3_metric_np
+    return fn(_V()).astype(np.float32)
+
+
+def _cfg(decomp=(1, 1, 1), **kw):
+    n_pf, n_pv, n_pr = decomp
+    return CometConfig(n_pf=n_pf, n_pv=n_pv, n_pr=n_pr, **kw)
+
+
+@functools.cache
+def _run(way, cfg, binary, stage):
+    V = _Vb() if binary else _V()
+    mesh = make_comet_mesh(cfg.n_pf, cfg.n_pv, cfg.n_pr)
+    if way == 2:
+        return czek2_distributed(V, mesh, cfg)
+    return czek3_distributed(V, mesh, cfg, stage=stage)
+
+
+def run2(decomp=(1, 1, 1), binary=False, **kw):
+    """A 2-way campaign, computed once per configuration."""
+    return _run(2, _cfg(decomp, **kw), binary, 0)
+
+
+def run3(decomp=(1, 1, 1), binary=False, stage=0, **kw):
+    """Stage ``stage`` of a 3-way campaign, computed once per configuration."""
+    return _run(3, _cfg(decomp, **kw), binary, stage)
+
+
+@functools.cache
+def _engine():
+    from repro.api import SimilarityEngine
+
+    return SimilarityEngine()
+
+
+@functools.cache
+def _dataset(name):
+    """A dataset directory: ``v15`` (V at 15 levels), ``binary`` (Vb at one
+    level) or ``appended`` (V's first N_OLD columns, then the rest
+    appended)."""
+    from repro.store import DatasetReader, append_dataset, write_dataset
+
+    path = os.path.join(_TMP.name, name)
+    if name == "v15":
+        write_dataset(path, _V(), levels=15, n_shards=2)
+    elif name == "binary":
+        write_dataset(path, _Vb(), levels=1, n_shards=2)
+    else:
+        write_dataset(path, _V()[:, :N_OLD], levels=15, n_shards=2)
+        append_dataset(path, _V()[:, N_OLD:])
+    DatasetReader(path).validate()
+    return path
+
+
+@functools.cache
+def _sharded(name):
+    from repro.store import DatasetReader
+
+    return DatasetReader(_dataset(name)).sharded()
+
+
+# -- 2-way decomposition invariance ------------------------------------------
+
+
+def check_2way(impl, decomp, levels=None):
+    """xla: the pair count, the oracle, the device checksum and count at
+    every decomposition.  Every other implementation — the Pallas fused
+    epilogue with the triangular diagonal schedule, the exact levels
+    decomposition, and the fused-levels campaign path (packed bit-planes
+    encoded once, ring-carried, MXU plane kernels; n_pf=2 emits raw psummed
+    partials for the merge epilogue) — must be bit-identical to it."""
+    kw = {} if levels is None else {"levels": levels}
+    out = run2(decomp, impl=impl, **kw)
+    c = out.checksum()
+    if impl == "xla":
         assert out.num_pairs() == N_V * (N_V - 1) // 2, (
-            f"2way {cfg}: {out.num_pairs()} pairs"
+            f"2way {decomp}: {out.num_pairs()} pairs"
         )
-        d = out.dense()
         iu = np.triu_indices(N_V, 1)
-        np.testing.assert_allclose(d[iu], ref_dense[iu], rtol=1e-6,
-                                   err_msg=f"2way {cfg} vs oracle")
-        c = out.checksum()
-        if ref_checksum is None:
-            ref_checksum = c
-        assert c == ref_checksum, f"2way checksum mismatch for {cfg}"
+        np.testing.assert_allclose(out.dense()[iu], _oracle(2)[iu],
+                                   rtol=1e-6, err_msg=f"2way {decomp} vs oracle")
         # the device partials fold to the host scan's checksum and count
-        assert out.device_raw[1] == out.num_pairs(), f"2way {cfg} count"
-        assert ck.combine([out.device_raw]) == c, f"2way {cfg} device"
-        print(f"  2way pf={n_pf} pv={n_pv} pr={n_pr}: OK ({hex(c)[:14]})")
-    # pallas fused-epilogue path inside the distributed engine (interpret
-    # mode): in-kernel assembly + triangular diagonal-block schedule must be
-    # bit-identical to the XLA out-of-kernel path
-    for n_pf, n_pv, n_pr in [(1, 2, 1), (1, 4, 1), (1, 2, 2)]:
-        cfg = CometConfig(n_pf=n_pf, n_pv=n_pv, n_pr=n_pr, impl="pallas")
-        out = czek2_distributed(V, make_comet_mesh(n_pf, n_pv, n_pr), cfg)
-        assert out.checksum() == ref_checksum, (
-            f"pallas impl changed results ({n_pf},{n_pv},{n_pr})"
-        )
-        print(f"  2way pallas impl pv={n_pv} pr={n_pr}: OK")
-    # packed upper-triangular storage: same entries, same checksum
-    packed = out.pack()
+        assert out.device_raw[1] == out.num_pairs(), f"2way {decomp} count"
+        assert ck.combine([out.device_raw]) == c, f"2way {decomp} device"
+    assert c == run2().checksum(), f"2way {impl} {decomp} changed results"
+
+
+def check_2way_packed():
+    """Packed upper-triangular storage: same entries, same checksum."""
+    packed = run2((1, 2, 2), impl="pallas").pack()
     assert packed.storage == "packed"
-    assert packed.checksum() == ref_checksum, "packing changed results"
-    print("  2way packed storage: OK")
-    # levels impl is exact for small-integer data
-    cfg = CometConfig(n_pf=1, n_pv=2, n_pr=1, impl="levels_xla", levels=15)
-    out = czek2_distributed(V, make_comet_mesh(1, 2, 1), cfg)
-    assert out.checksum() == ref_checksum, "levels impl not bit-exact"
-    print("  2way levels impl: OK")
-    # fused-levels campaign path: packed bit-planes encoded once, ring-
-    # carried, MXU plane kernels with in-kernel epilogue + triangular
-    # diagonal schedule; n_pf=2 keeps the fused MXU kernels but emits raw
-    # psummed partials assembled by the out-of-kernel merge epilogue.
-    # All bit-identical to the xla reference.
-    for n_pf, n_pv, n_pr in [(1, 2, 1), (1, 4, 1), (1, 2, 2), (2, 2, 1)]:
-        cfg = CometConfig(n_pf=n_pf, n_pv=n_pv, n_pr=n_pr, impl="levels",
-                          levels=15)
-        out = czek2_distributed(V, make_comet_mesh(n_pf, n_pv, n_pr), cfg)
-        assert out.checksum() == ref_checksum, (
-            f"fused-levels changed results ({n_pf},{n_pv},{n_pr})"
-        )
-        print(f"  2way fused-levels pf={n_pf} pv={n_pv} pr={n_pr}: OK")
+    assert packed.checksum() == run2().checksum(), "packing changed results"
 
 
-def check_3way(V, ref_dense):
-    ref_checksum = None
-    configs = [  # (n_pf, n_pv, n_pr, n_st)
-        (1, 1, 1, 1),
-        (1, 2, 1, 1),
-        (1, 4, 1, 1),
-        (2, 2, 1, 1),
-        (1, 2, 2, 1),
-        (1, 2, 4, 1),
-        (2, 2, 2, 1),
-    ]
-    n_unique = N_V * (N_V - 1) * (N_V - 2) // 6
-    for n_pf, n_pv, n_pr, n_st in configs:
-        cfg = CometConfig(n_pf=n_pf, n_pv=n_pv, n_pr=n_pr, n_st=n_st)
-        mesh = make_comet_mesh(n_pf, n_pv, n_pr)
-        out = czek3_distributed(V, mesh, cfg, stage=0)
+# -- 3-way decomposition invariance ------------------------------------------
+
+
+def check_3way(impl, decomp, levels=None, encoding=None):
+    """xla: the triple count, the oracle, the device checksum and count at
+    every decomposition.  Every other path — the fused X_j pipeline-step
+    kernels, the packed bit-plane ring (planes encoded once, ring-carried
+    through Phases B/C; n_pf=2 shards the byte axis over "pf"), the ring
+    with the unfused slice contraction (levels_xla), and the value ring
+    (encoding="none") — must be bit-identical to it."""
+    kw = {} if levels is None else {"levels": levels}
+    if encoding is not None:
+        kw["encoding"] = encoding
+    out = run3(decomp, impl=impl, **kw)
+    c = out.checksum()
+    if impl == "xla":
+        n_unique = N_V * (N_V - 1) * (N_V - 2) // 6
         assert out.num_triples() == n_unique, (
-            f"3way {cfg}: {out.num_triples()} != {n_unique}"
+            f"3way {decomp}: {out.num_triples()} != {n_unique}"
         )
-        d = out.dense()
-        errs = []
-        for i in range(N_V):
-            for j in range(i + 1, N_V):
-                for k in range(j + 1, N_V):
-                    errs.append(abs(d[i, j, k] - ref_dense[i, j, k]))
-        assert max(errs) < 1e-6, f"3way {cfg}: max err {max(errs)}"
-        c = out.checksum()
-        if ref_checksum is None:
-            ref_checksum = c
-        assert c == ref_checksum, f"3way checksum mismatch for {cfg}"
-        assert out.device_raw[1] == n_unique, f"3way {cfg} count"
-        assert ck.combine([out.device_raw]) == c, f"3way {cfg} device"
-        print(f"  3way pf={n_pf} pv={n_pv} pr={n_pr}: OK ({hex(c)[:14]})")
+        d, ref = out.dense(), _oracle(3)
+        err = max(abs(d[i, j, k] - ref[i, j, k])
+                  for i in range(N_V) for j in range(i + 1, N_V)
+                  for k in range(j + 1, N_V))
+        assert err < 1e-6, f"3way {decomp}: max err {err}"
+        assert out.device_raw[1] == n_unique, f"3way {decomp} count"
+        assert ck.combine([out.device_raw]) == c, f"3way {decomp} device"
+    assert c == run3().checksum(), f"3way {impl} {kw} {decomp} changed results"
 
-    # pallas path: fused X_j pipeline-step kernels, bit-identical numerators
-    cfg = CometConfig(n_pf=1, n_pv=2, n_pr=1, impl="pallas")
-    out = czek3_distributed(V, make_comet_mesh(1, 2, 1), cfg, stage=0)
-    assert out.checksum() == ref_checksum, "3way pallas impl changed results"
-    print("  3way pallas impl: OK")
 
-    # packed bit-plane ring (path3 == "fused-levels-ring"): planes encoded
-    # once before shard_map, ring-carried through Phases B/C, pipeline
-    # slices fed to the level-decomposed kernels as byte-range views.
-    # n_pf=2 shards the BYTE axis over "pf"; all bit-identical to xla.
-    for n_pf, n_pv, n_pr in [(1, 2, 1), (2, 2, 1), (1, 2, 2), (1, 4, 1)]:
-        cfg = CometConfig(n_pf=n_pf, n_pv=n_pv, n_pr=n_pr, impl="levels",
-                          levels=15)
-        out = czek3_distributed(V, make_comet_mesh(n_pf, n_pv, n_pr), cfg,
-                                stage=0)
-        assert out.checksum() == ref_checksum, (
-            f"3way plane ring changed results ({n_pf},{n_pv},{n_pr})"
-        )
-        print(f"  3way fused-levels-ring pf={n_pf} pv={n_pv} pr={n_pr}: OK")
-
-    # plane ring with the UNFUSED slice contraction (impl=levels_xla):
-    # the ring still carries packed planes, X_j is a packed AND
-    cfg = CometConfig(n_pf=2, n_pv=2, n_pr=1, impl="levels_xla", levels=15)
-    out = czek3_distributed(V, make_comet_mesh(2, 2, 1), cfg, stage=0)
-    assert out.checksum() == ref_checksum, "3way levels_xla plane ring"
-    print("  3way plane ring unfused (levels_xla) pf=2 pv=2: OK")
-
-    # encoding="none" opt-out keeps the value ring + per-slice encode
-    cfg = CometConfig(n_pf=1, n_pv=2, n_pr=1, impl="levels", levels=15,
-                      encoding="none")
-    out = czek3_distributed(V, make_comet_mesh(1, 2, 1), cfg, stage=0)
-    assert out.checksum() == ref_checksum, "3way value-ring fallback"
-    print("  3way fused-levels value ring (encoding=none): OK")
-
-    # staging: union over stages == the full result set, bit-identical
-    cfg = CometConfig(n_pf=1, n_pv=2, n_pr=1, n_st=2)
-    mesh = make_comet_mesh(1, 2, 1)
-    parts = []
-    total = 0
+def check_3way_staged():
+    """Staging: the union over stages is the full result set, bit-identical."""
+    n_unique = N_V * (N_V - 1) * (N_V - 2) // 6
+    total, parts = 0, []
     for stage in range(2):
-        out = czek3_distributed(V, mesh, cfg, stage=stage)
+        out = run3((1, 2, 1), stage=stage, n_st=2)
         total += out.num_triples()
         parts.extend(ck.raw_triples(I, J, K, W) for I, J, K, W in out.entries())
     assert total == n_unique, f"staged union {total} != {n_unique}"
-    assert ck.combine(parts) == ref_checksum, "staged checksum mismatch"
-    print("  3way staging n_st=2: OK")
+    assert ck.combine(parts) == run3().checksum(), "staged checksum mismatch"
 
 
-def check_engine_parity(V):
-    """The unified SimilarityEngine must reproduce the exact per-campaign
-    checksums of the direct czek2/czek3 paths for several decompositions
-    (the api_redesign acceptance contract), and the registry's CCC metric
-    must be decomposition-invariant and match its numpy oracle."""
-    from repro.api import SimilarityEngine, SimilarityRequest, get_metric
+# -- the unified engine ------------------------------------------------------
 
-    engine = SimilarityEngine()
-    for n_pf, n_pv, n_pr in [(1, 1, 1), (1, 4, 1), (2, 2, 2), (1, 2, 2)]:
-        cfg = CometConfig(n_pf=n_pf, n_pv=n_pv, n_pr=n_pr)
-        mesh = make_comet_mesh(n_pf, n_pv, n_pr)
-        want2 = czek2_distributed(V, mesh, cfg).checksum()
-        got2 = engine.run(
-            SimilarityRequest(way=2, n_pf=n_pf, n_pv=n_pv, n_pr=n_pr), V
-        ).checksum()
-        assert got2 == want2, f"engine 2way checksum != direct ({n_pf},{n_pv},{n_pr})"
-        want3 = czek3_distributed(V, mesh, cfg, stage=0).checksum()
-        got3 = engine.run(
-            SimilarityRequest(way=3, n_pf=n_pf, n_pv=n_pv, n_pr=n_pr), V
-        ).checksum()
-        assert got3 == want3, f"engine 3way checksum != direct ({n_pf},{n_pv},{n_pr})"
-        print(f"  engine parity pf={n_pf} pv={n_pv} pr={n_pr}: OK")
 
-    # CCC: decomposition-invariant checksum + oracle match (fp32 tolerance)
-    ccc_ref = None
-    oracle = get_metric("ccc").oracle2(V).astype(np.float32)
-    iu = np.triu_indices(V.shape[1], 1)
-    for n_pf, n_pv, n_pr in [(1, 1, 1), (1, 4, 1), (2, 2, 2)]:
-        out = engine.run(
-            SimilarityRequest(metric="ccc", way=2,
-                              n_pf=n_pf, n_pv=n_pv, n_pr=n_pr), V
-        )
-        d = out.dense()
-        np.testing.assert_allclose(d[iu], oracle[iu], rtol=1e-5,
-                                   err_msg=f"ccc ({n_pf},{n_pv},{n_pr})")
-        c = out.checksum()
-        if ccc_ref is None:
-            ccc_ref = c
-        assert c == ccc_ref, "ccc checksum varies with decomposition"
-        print(f"  ccc pf={n_pf} pv={n_pv} pr={n_pr}: OK ({hex(c)[:14]})")
+def check_engine_parity(way, decomp):
+    """The unified SimilarityEngine reproduces the exact checksum of the
+    direct czek2/czek3 path at this decomposition."""
+    from repro.api import SimilarityRequest
 
-    # the generated fused kernel serves CCC too (metric-generic epilogue):
-    # integer data -> exact numerators -> bit-identical to the XLA path
-    out = engine.run(
-        SimilarityRequest(metric="ccc", way=2, n_pv=2, impl="pallas"), V
+    n_pf, n_pv, n_pr = decomp
+    want = (run2(decomp) if way == 2 else run3(decomp)).checksum()
+    got = _engine().run(
+        SimilarityRequest(way=way, n_pf=n_pf, n_pv=n_pv, n_pr=n_pr), _V()
+    ).checksum()
+    assert got == want, f"engine {way}way checksum != direct {decomp}"
+
+
+@functools.cache
+def _ccc(decomp, impl="xla"):
+    from repro.api import SimilarityRequest
+
+    n_pf, n_pv, n_pr = decomp
+    return _engine().run(
+        SimilarityRequest(metric="ccc", way=2, n_pf=n_pf, n_pv=n_pv,
+                          n_pr=n_pr, impl=impl), _V())
+
+
+def check_ccc(decomp):
+    """The registry's CCC metric matches its numpy oracle (fp32 tolerance)
+    and its checksum does not vary with the decomposition."""
+    from repro.api import get_metric
+
+    oracle = get_metric("ccc").oracle2(_V()).astype(np.float32)
+    iu = np.triu_indices(N_V, 1)
+    out = _ccc(decomp)
+    np.testing.assert_allclose(out.dense()[iu], oracle[iu], rtol=1e-5,
+                               err_msg=f"ccc {decomp}")
+    assert out.checksum() == _ccc((1, 1, 1)).checksum(), (
+        "ccc checksum varies with decomposition"
     )
-    assert out.checksum() == ccc_ref, "ccc pallas fused path changed results"
-    print("  ccc pallas fused epilogue: OK")
 
 
-def check_plane_store(V):
-    """Campaigns loaded from a repro.store dataset (pre-encoded packed
-    planes, mmap -> ring) must be bit-identical to the in-memory matrix on
-    BOTH engines across decompositions — including byte-axis "pf" sharding
-    of the on-disk field shards — and must never run the host encoder."""
-    import tempfile
+def check_ccc_pallas():
+    """The generated fused kernel serves CCC too (metric-generic epilogue):
+    integer data -> exact numerators -> bit-identical to the XLA path."""
+    got = _ccc((1, 2, 1), impl="pallas").checksum()
+    assert got == _ccc((1, 1, 1)).checksum(), "ccc pallas fused path changed results"
 
+
+# -- store-backed campaigns --------------------------------------------------
+
+
+@functools.cache
+def _store_checksum(way, decomp):
+    from repro.api import InputSpec, SimilarityRequest
+
+    n_pf, n_pv, n_pr = decomp
+    return _engine().run(SimilarityRequest(
+        way=way, impl="levels", levels=15, n_pf=n_pf, n_pv=n_pv, n_pr=n_pr,
+        input=InputSpec(source="planes", path=_dataset("v15")),
+    )).checksum()
+
+
+def check_plane_store(way, decomp):
+    """A campaign loaded from a repro.store dataset (pre-encoded packed
+    planes, mmap -> ring) is bit-identical to the in-memory matrix, also
+    with byte-axis "pf" sharding of the on-disk field shards, and never
+    runs the host encoder."""
     import repro.kernels.mgemm_levels as mgemm_levels
-    from repro.api import InputSpec, SimilarityEngine, SimilarityRequest
-    from repro.store import DatasetReader, write_dataset
+    from repro.api import SimilarityRequest
 
-    with tempfile.TemporaryDirectory() as tmp:
-        write_dataset(tmp, V, levels=15, n_shards=2)
-        DatasetReader(tmp).validate()
-        engine = SimilarityEngine()
-        spec = InputSpec(source="planes", path=tmp)
+    n_pf, n_pv, n_pr = decomp
+    calls = {"n": 0}
+    orig = mgemm_levels.encode_bitplanes_np
 
-        calls = {"n": 0}
-        orig = mgemm_levels.encode_bitplanes_np
+    def counted(*args, **kwargs):
+        calls["n"] += 1
+        return orig(*args, **kwargs)
 
-        def counted(*args, **kwargs):
-            calls["n"] += 1
-            return orig(*args, **kwargs)
-
-        mgemm_levels.encode_bitplanes_np = counted
-        try:
-            for way in (2, 3):
-                ref = None
-                for n_pf, n_pv, n_pr in [(1, 2, 1), (2, 2, 1), (1, 4, 1)]:
-                    base = SimilarityRequest(
-                        way=way, impl="levels", levels=15,
-                        n_pf=n_pf, n_pv=n_pv, n_pr=n_pr,
-                    )
-                    before = calls["n"]
-                    want = engine.run(base, V).checksum()
-                    assert calls["n"] > before, "in-memory path should encode"
-                    before = calls["n"]
-                    got = engine.run(
-                        SimilarityRequest(
-                            way=way, impl="levels", levels=15,
-                            n_pf=n_pf, n_pv=n_pv, n_pr=n_pr, input=spec,
-                        )
-                    ).checksum()
-                    assert calls["n"] == before, (
-                        f"{way}-way plane-store campaign ran the host encoder"
-                    )
-                    assert got == want, (
-                        f"{way}-way store checksum != in-memory "
-                        f"({n_pf},{n_pv},{n_pr})"
-                    )
-                    if ref is None:
-                        ref = got
-                    assert got == ref, f"{way}-way store checksum varies"
-                    print(f"  {way}-way store pf={n_pf} pv={n_pv} pr={n_pr}: "
-                          f"OK (zero-encode)")
-        finally:
-            mgemm_levels.encode_bitplanes_np = orig
+    mgemm_levels.encode_bitplanes_np = counted
+    try:
+        before = calls["n"]
+        want = _engine().run(SimilarityRequest(
+            way=way, impl="levels", levels=15, n_pf=n_pf, n_pv=n_pv,
+            n_pr=n_pr), _V()).checksum()
+        assert calls["n"] > before, "in-memory path should encode"
+        before = calls["n"]
+        got = _store_checksum(way, decomp)
+        assert calls["n"] == before, (
+            f"{way}-way plane-store campaign ran the host encoder"
+        )
+    finally:
+        mgemm_levels.encode_bitplanes_np = orig
+    assert got == want, f"{way}-way store checksum != in-memory {decomp}"
+    assert got == _store_checksum(way, (1, 2, 1)), (
+        f"{way}-way store checksum varies"
+    )
 
 
-def check_streamed(V):
-    """Streamed campaigns (repro.stream) under multi-device meshes: the
-    chunked deferred-flush pipeline + cross-shard merge epilogue must be
-    bit-identical to the in-memory engines for 2-way AND 3-way, including
-    byte-axis "pf" sharding of the chunks and a budget that forces >1
-    chunk per shard."""
-    import tempfile
-
-    from repro.store import DatasetReader, write_dataset
-    from repro.stream import stream_twoway, stream_threeway
-
-    want2 = czek2_distributed(
-        V, make_comet_mesh(1, 1, 1), CometConfig()).checksum()
-    want3 = czek3_distributed(
-        V, make_comet_mesh(1, 1, 1), CometConfig(), stage=0).checksum()
-    with tempfile.TemporaryDirectory() as tmp:
-        write_dataset(tmp, V, levels=15, n_shards=2)
-        sh = DatasetReader(tmp).sharded()
-        for n_pf, n_pv, n_pr, budget in [
-            (1, 2, 1, 0),          # shard-per-chunk default
-            (2, 2, 1, 0),          # byte axis split over "pf" per chunk
-            # tight budget -> 1-byte chunks (2 * levels * n_v * 1 = 720
-            # bytes double-buffered fits; a whole shard would not)
-            (1, 2, 2, 800),
-        ]:
-            cfg = CometConfig(n_pf=n_pf, n_pv=n_pv, n_pr=n_pr,
-                              impl="levels", levels=15, streaming="on",
-                              max_host_bytes=budget)
-            mesh = make_comet_mesh(n_pf, n_pv, n_pr)
-            out2, info2 = stream_twoway(sh, mesh, cfg)
-            assert out2.checksum() == want2, (
-                f"streamed 2way != in-memory ({n_pf},{n_pv},{n_pr})"
-            )
-            out3, info3 = stream_threeway(sh, mesh, cfg, stage=0)
-            assert out3.checksum() == want3, (
-                f"streamed 3way != in-memory ({n_pf},{n_pv},{n_pr})"
-            )
-            if budget:
-                assert info2["peak_host_bytes"] <= budget, info2
-                assert info2["chunks"] > sh.n_shards, info2
-            print(f"  streamed pf={n_pf} pv={n_pv} pr={n_pr} "
-                  f"chunks={info2['chunks']}: OK")
+# -- streamed campaigns ------------------------------------------------------
 
 
-def check_binary_popcount(Vb):
-    """Binary ({0,1}) campaigns: levels=1 resolves to the popcount bit-GEMM
-    (path == "fused-popcount") on BOTH engines, in-memory / store-backed /
-    streamed, with checksums bit-identical to impl="xla" across
-    decompositions — and the sorenson metric rides the same machinery."""
-    import tempfile
+def check_streamed(way, decomp, budget):
+    """A streamed campaign (repro.stream: the chunked deferred-flush
+    pipeline and the cross-shard merge epilogue) is bit-identical to the
+    in-memory engine, also with byte-axis "pf" sharding of the chunks and a
+    budget that forces more than one chunk per shard."""
+    from repro.stream import stream_threeway, stream_twoway
 
-    from repro.api import InputSpec, SimilarityEngine, SimilarityRequest
+    sh = _sharded("v15")
+    cfg = _cfg(decomp, impl="levels", levels=15, streaming="on",
+               max_host_bytes=budget)
+    mesh = make_comet_mesh(*decomp)
+    if way == 2:
+        out, info = stream_twoway(sh, mesh, cfg)
+        want = run2().checksum()
+    else:
+        out, info = stream_threeway(sh, mesh, cfg, stage=0)
+        want = run3().checksum()
+    assert out.checksum() == want, f"streamed {way}way != in-memory {decomp}"
+    if budget and way == 2:
+        assert info["peak_host_bytes"] <= budget, info
+        assert info["chunks"] > sh.n_shards, info
+
+
+# -- binary data on the popcount bit-GEMM ------------------------------------
+
+
+def _binary_want(way):
+    run = run2 if way == 2 else run3
+    return run(binary=True, impl="xla", levels=1).checksum()
+
+
+def check_binary_popcount(decomp):
+    """levels=1 resolves to the popcount bit-GEMM (path "fused-popcount")
+    and is bit-identical to impl="xla" for 2-way and 3-way, including the
+    n_pf=2 merge epilogue."""
     from repro.core.metric_spec import CZEKANOWSKI
     from repro.core.tile_executor import TileExecutor
     from repro.core.twoway import resolve_config
-    from repro.store import DatasetReader, write_dataset
-    from repro.stream import stream_twoway, stream_threeway
 
-    want2 = czek2_distributed(
-        Vb, make_comet_mesh(1, 1, 1), CometConfig(impl="xla", levels=1)
+    cfg = _cfg(decomp, impl="levels", levels=1)
+    rcfg = resolve_config(cfg, _Vb(), CZEKANOWSKI)
+    ex = TileExecutor(cfg=rcfg, metric=CZEKANOWSKI, axis=None)
+    assert ex.path == "fused-popcount", (decomp, ex.path)
+    assert ex.path3 == "fused-popcount-ring", (decomp, ex.path3)
+    got2 = run2(decomp, binary=True, impl="levels", levels=1).checksum()
+    assert got2 == _binary_want(2), f"popcount 2way != xla {decomp}"
+    got3 = run3(decomp, binary=True, impl="levels", levels=1).checksum()
+    assert got3 == _binary_want(3), f"popcount 3way != xla {decomp}"
+
+
+def check_sorenson(impl):
+    """The sorenson metric on binary data: the same arithmetic, so the same
+    checksum as the xla Czekanowski reference, for every impl."""
+    from repro.api import SimilarityRequest
+
+    got = _engine().run(
+        SimilarityRequest(metric="sorenson", way=2, n_pv=2, impl=impl,
+                          levels=1), _Vb(),
     ).checksum()
-    want3 = czek3_distributed(
-        Vb, make_comet_mesh(1, 1, 1), CometConfig(impl="xla", levels=1),
-        stage=0,
-    ).checksum()
-
-    # in-memory, >= 3 decompositions incl. the n_pf=2 merge epilogue
-    for n_pf, n_pv, n_pr in [(1, 2, 1), (1, 2, 2), (2, 2, 1), (1, 4, 1)]:
-        cfg = CometConfig(n_pf=n_pf, n_pv=n_pv, n_pr=n_pr, impl="levels",
-                          levels=1)
-        rcfg = resolve_config(cfg, Vb, CZEKANOWSKI)
-        ex = TileExecutor(cfg=rcfg, metric=CZEKANOWSKI, axis=None)
-        assert ex.path == "fused-popcount", (n_pf, ex.path)
-        assert ex.path3 == "fused-popcount-ring", (n_pf, ex.path3)
-        mesh = make_comet_mesh(n_pf, n_pv, n_pr)
-        out2 = czek2_distributed(Vb, mesh, cfg)
-        assert out2.checksum() == want2, (
-            f"popcount 2way != xla ({n_pf},{n_pv},{n_pr})"
-        )
-        out3 = czek3_distributed(Vb, mesh, cfg, stage=0)
-        assert out3.checksum() == want3, (
-            f"popcount 3way != xla ({n_pf},{n_pv},{n_pr})"
-        )
-        print(f"  binary popcount pf={n_pf} pv={n_pv} pr={n_pr}: OK "
-              f"(2way+3way)")
-
-    # sorenson: same arithmetic on binary data -> same checksums, every impl
-    engine = SimilarityEngine()
-    for impl, levels in [("xla", 1), ("pallas", 1), ("levels", 1),
-                         ("levels_xla", 1)]:
-        got = engine.run(
-            SimilarityRequest(metric="sorenson", way=2, n_pv=2, impl=impl,
-                              levels=levels), Vb,
-        ).checksum()
-        assert got == want2, f"sorenson {impl} != xla reference"
-    print("  sorenson parity (xla/pallas/popcount/levels_xla): OK")
-
-    # store-backed + streamed binary campaigns stay on popcount partials
-    with tempfile.TemporaryDirectory() as tmp:
-        write_dataset(tmp, Vb, levels=1, n_shards=2)
-        got = engine.run(
-            SimilarityRequest(
-                way=2, n_pv=2, impl="levels", levels=1,
-                input=InputSpec(source="planes", path=tmp),
-            )
-        ).checksum()
-        assert got == want2, "binary store campaign != xla"
-        print("  binary store-backed campaign: OK")
-        sh = DatasetReader(tmp).sharded()
-        cfg = CometConfig(n_pv=2, impl="levels", levels=1, streaming="on")
-        dex = TileExecutor(cfg=CometConfig(impl="levels", levels=1,
-                                           encoding="bitplane"),
-                           deferred=True)
-        assert dex.path == "streamed-fused-popcount", dex.path
-        assert dex.path3 == "streamed-fused-popcount-ring", dex.path3
-        mesh = make_comet_mesh(1, 2, 1)
-        out2, info2 = stream_twoway(sh, mesh, cfg)
-        assert out2.checksum() == want2, "streamed binary 2way != xla"
-        out3, info3 = stream_threeway(sh, mesh, cfg, stage=0)
-        assert out3.checksum() == want3, "streamed binary 3way != xla"
-        print(f"  binary streamed chunks={info2['chunks']}: OK (2way+3way)")
+    assert got == _binary_want(2), f"sorenson {impl} != xla reference"
 
 
-def check_delta(V):
-    """Border-block delta campaigns under multi-device meshes: for a split
-    n_old | n_new of V's columns, compute the prior on [0, n_old), run the
-    delta program (new-vs-all rectangle + new-vs-new triangle, NO ring)
-    across decompositions — including the n_pf=2 merge-epilogue case and a
-    streamed run — merge into the packed prior, and require checksums
-    BIT-IDENTICAL to the full recompute.  Accounting must report
-    border-proportional compute with zero ring payload bytes."""
-    import tempfile
+def check_binary_store():
+    """A store-backed binary campaign stays on popcount partials."""
+    from repro.api import InputSpec, SimilarityRequest
 
+    got = _engine().run(SimilarityRequest(
+        way=2, n_pv=2, impl="levels", levels=1,
+        input=InputSpec(source="planes", path=_dataset("binary")),
+    )).checksum()
+    assert got == _binary_want(2), "binary store campaign != xla"
+
+
+def check_binary_streamed():
+    """A streamed binary campaign takes the streamed popcount paths and is
+    bit-identical to xla for 2-way and 3-way."""
+    from repro.core.tile_executor import TileExecutor
+    from repro.stream import stream_threeway, stream_twoway
+
+    dex = TileExecutor(cfg=CometConfig(impl="levels", levels=1,
+                                       encoding="bitplane"), deferred=True)
+    assert dex.path == "streamed-fused-popcount", dex.path
+    assert dex.path3 == "streamed-fused-popcount-ring", dex.path3
+    sh = _sharded("binary")
+    cfg = CometConfig(n_pv=2, impl="levels", levels=1, streaming="on")
+    mesh = make_comet_mesh(1, 2, 1)
+    out2, _ = stream_twoway(sh, mesh, cfg)
+    assert out2.checksum() == _binary_want(2), "streamed binary 2way != xla"
+    out3, _ = stream_threeway(sh, mesh, cfg, stage=0)
+    assert out3.checksum() == _binary_want(3), "streamed binary 3way != xla"
+
+
+# -- border-block delta campaigns --------------------------------------------
+
+
+@functools.cache
+def _prior(impl):
+    """The packed full campaign over V's first N_OLD columns."""
+    base = CometConfig(impl=impl, levels=15)
+    return czek2_distributed(
+        _V()[:, :N_OLD], make_comet_mesh(1, 1, 1), base
+    ).pack()
+
+
+def check_delta(impl, decomp):
+    """For the split N_OLD | N_V - N_OLD of V's columns, the delta program
+    (new-vs-all rectangle + new-vs-new triangle, NO ring) merged into the
+    packed prior is BIT-IDENTICAL to the full recompute, with
+    border-proportional compute and zero ring payload bytes."""
     from repro.core.delta import merge_delta, twoway_delta
-    from repro.store import DatasetReader, append_dataset, write_dataset
+
+    want = run2(impl=impl, levels=15).checksum()
+    cfg = _cfg(decomp, impl=impl, levels=15)
+    rect, tri, rcfg, info = twoway_delta(_V(), N_OLD, make_comet_mesh(*decomp),
+                                         cfg)
+    merged = merge_delta(_prior(impl), rect, tri, N_OLD, N_V - N_OLD,
+                         rcfg.out_dtype)
+    assert merged.checksum() == want, f"delta {impl} != full {decomp}"
+    assert info["ring_payload_bytes"] == 0, info
+    assert info["computed_entries"] < info["full_entries"], info
+
+
+def check_delta_streamed(decomp, budget):
+    """The streamed delta over an APPENDED store dataset (byte-column
+    append), also with a budget forcing more than one chunk per shard."""
+    from repro.core.delta import merge_delta
     from repro.stream import stream_twoway_delta
 
-    n_old = 15
-    m = N_V - n_old
-    for impl, levels in [("xla", 15), ("levels", 15)]:
-        base = CometConfig(impl=impl, levels=levels)
-        want = czek2_distributed(V, make_comet_mesh(1, 1, 1), base).checksum()
-        prior = czek2_distributed(
-            V[:, :n_old], make_comet_mesh(1, 1, 1), base
-        ).pack()
-        for n_pf, n_pv, n_pr in [(1, 1, 1), (1, 2, 2), (2, 2, 1), (1, 4, 2),
-                                 (2, 2, 2)]:
-            cfg = CometConfig(n_pf=n_pf, n_pv=n_pv, n_pr=n_pr, impl=impl,
-                              levels=levels)
-            mesh = make_comet_mesh(n_pf, n_pv, n_pr)
-            rect, tri, rcfg, info = twoway_delta(V, n_old, mesh, cfg)
-            merged = merge_delta(prior, rect, tri, n_old, m, rcfg.out_dtype)
-            assert merged.checksum() == want, (
-                f"delta {impl} != full ({n_pf},{n_pv},{n_pr})"
-            )
-            assert info["ring_payload_bytes"] == 0, info
-            assert info["computed_entries"] < info["full_entries"], info
-            print(f"  delta {impl} pf={n_pf} pv={n_pv} pr={n_pr}: OK "
-                  f"({info['computed_entries']}/{info['full_entries']} "
-                  f"entries)")
-
-    # streamed delta over an APPENDED store dataset (byte-column append),
-    # multi-device + a budget forcing >1 chunk per shard, incl. the n_pf=2
-    # merge-epilogue case
-    base = CometConfig(impl="levels", levels=15)
-    want = czek2_distributed(V, make_comet_mesh(1, 1, 1), base).checksum()
-    prior = czek2_distributed(
-        V[:, :n_old], make_comet_mesh(1, 1, 1), base
-    ).pack()
-    with tempfile.TemporaryDirectory() as tmp:
-        write_dataset(tmp, V[:, :n_old], levels=15, n_shards=2)
-        append_dataset(tmp, V[:, n_old:])
-        sh = DatasetReader(tmp).sharded()
-        for n_pf, n_pv, n_pr, budget in [(1, 2, 1, 0), (2, 2, 1, 0),
-                                         (1, 2, 2, 800)]:
-            cfg = CometConfig(n_pf=n_pf, n_pv=n_pv, n_pr=n_pr, impl="levels",
-                              levels=15, streaming="on",
-                              max_host_bytes=budget)
-            mesh = make_comet_mesh(n_pf, n_pv, n_pr)
-            rect, tri, rcfg, dinfo, sinfo = stream_twoway_delta(
-                sh, n_old, mesh, cfg
-            )
-            merged = merge_delta(prior, rect, tri, n_old, m, rcfg.out_dtype)
-            assert merged.checksum() == want, (
-                f"streamed delta != full ({n_pf},{n_pv},{n_pr})"
-            )
-            assert dinfo["streamed"] and dinfo["ring_payload_bytes"] == 0
-            if budget:
-                assert sinfo["peak_host_bytes"] <= budget, sinfo
-                assert sinfo["chunks"] > sh.n_shards, sinfo
-            print(f"  streamed delta pf={n_pf} pv={n_pv} pr={n_pr} "
-                  f"chunks={sinfo['chunks']}: OK")
+    want = run2(impl="levels", levels=15).checksum()
+    sh = _sharded("appended")
+    cfg = _cfg(decomp, impl="levels", levels=15, streaming="on",
+               max_host_bytes=budget)
+    rect, tri, rcfg, dinfo, sinfo = stream_twoway_delta(
+        sh, N_OLD, make_comet_mesh(*decomp), cfg
+    )
+    merged = merge_delta(_prior("levels"), rect, tri, N_OLD, N_V - N_OLD,
+                         rcfg.out_dtype)
+    assert merged.checksum() == want, f"streamed delta != full {decomp}"
+    assert dinfo["streamed"] and dinfo["ring_payload_bytes"] == 0
+    if budget:
+        assert sinfo["peak_host_bytes"] <= budget, sinfo
+        assert sinfo["chunks"] > sh.n_shards, sinfo
 
 
 def main():
-    V = random_integer_vectors(N_F, N_V, max_value=15, seed=42)
-    print("2-way decomposition invariance:")
-    check_2way(V, czek2_metric_np(V).astype(np.float32))
-    print("3-way decomposition invariance:")
-    check_3way(V, czek3_metric_np(V).astype(np.float32))
-    print("unified engine parity (api redesign contract):")
-    check_engine_parity(V)
-    print("plane-store zero-encode campaigns (repro.store):")
-    check_plane_store(V)
-    print("streamed campaigns (repro.stream):")
-    check_streamed(V)
-    print("binary popcount campaigns (kernels/popgemm):")
-    check_binary_popcount(random_integer_vectors(N_F, N_V, max_value=1,
-                                                 seed=43))
-    print("border-block delta campaigns (repro.core.delta):")
-    check_delta(V)
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--out", help="write one JSON record per case here")
+    args = ap.parse_args()
+    sink = open(args.out, "w") if args.out else None
+    failed = 0
+    with _TMP:
+        for case_id, check, params in CASES:
+            t0 = time.perf_counter()
+            try:
+                globals()["check_" + check](**params)
+                ok, error = True, ""
+            except Exception:
+                ok, error = False, traceback.format_exc()[-3000:]
+            seconds = time.perf_counter() - t0
+            failed += not ok
+            print(f"  {case_id}: {'OK' if ok else 'FAILED'} ({seconds:.2f} s)",
+                  flush=True)
+            if error:
+                print(error, flush=True)
+            if sink:
+                sink.write(json.dumps({"id": case_id, "ok": ok, "error": error,
+                                       "seconds": seconds}) + "\n")
+                sink.flush()
+    if sink:
+        sink.close()
+    if failed:
+        print(f"{failed} of {len(CASES)} DISTRIBUTED CHECKS FAILED")
+        raise SystemExit(1)
     print("ALL DISTRIBUTED CHECKS PASSED")
 
 

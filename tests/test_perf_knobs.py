@@ -1,15 +1,8 @@
-"""Correctness of the §Perf hillclimb knobs: every optimization must keep
-results (bit-)exact or within documented tolerance vs the baseline."""
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.configs.registry import get_smoke_config
-from repro.core.metrics import czek2_metric_np
+"""Correctness of the int8 ring-payload knob: it must keep results
+bit-exact vs the baseline."""
 from repro.core.synthetic import random_integer_vectors
 from repro.core.twoway import CometConfig, czek2_distributed
 from repro.core.threeway import czek3_distributed
-from repro.models import api
 from repro.parallel.mesh import make_comet_mesh
 
 
@@ -40,66 +33,3 @@ def test_int8_ring_with_levels_impl():
         CometConfig(impl="levels_xla", levels=2, ring_dtype="int8"),
     )
     assert base.checksum() == opt.checksum()
-
-
-def test_seq_parallel_same_loss():
-    """seq_parallel only changes sharding constraints — identical math."""
-    cfg = get_smoke_config("llama3-8b")
-    params = api.init_model(cfg, jax.random.PRNGKey(0))
-    batch = {
-        "tokens": jnp.arange(64, dtype=jnp.int32).reshape(2, 32) % cfg.vocab_size,
-        "labels": jnp.arange(64, dtype=jnp.int32).reshape(2, 32) % cfg.vocab_size,
-    }
-    l0 = float(api.model_loss(cfg, params, batch))
-    l1 = float(api.model_loss(cfg.replace(seq_parallel=True), params, batch))
-    assert l0 == l1  # no mesh active -> constraints are no-ops, math identical
-
-
-def test_flash_p_bf16_close():
-    cfg = get_smoke_config("llama3-8b")
-    params = api.init_model(cfg, jax.random.PRNGKey(1))
-    tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 64), 0, cfg.vocab_size)
-    base, _ = api.model_forward(cfg, params, {"tokens": tokens})
-    # force the flash path with a tiny threshold via long-enough seq? smoke
-    # seq is small; exercise _flash_attend directly instead
-    from repro.models.attention import _flash_attend
-
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((2, 64, 4, 16)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((2, 64, 4, 16)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((2, 64, 4, 16)), jnp.float32)
-    a = _flash_attend(q, k, v, causal=True, cq=16, ck=16)
-    b = _flash_attend(q, k, v, causal=True, cq=16, ck=16, p_bf16=True)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-2)
-    # and flash agrees with dense reference
-    from repro.models.attention import _dense_attend
-
-    d = _dense_attend(q, k, v, causal=True, q_offset=0)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(d), rtol=2e-4, atol=2e-5)
-
-
-def test_moe_chunked_dispatch_close():
-    """Chunked dispatch: same expert math, per-chunk capacity; outputs must
-    match the global dispatch wherever no token was dropped."""
-    cfg = get_smoke_config("grok-1-314b").replace(capacity_factor=4.0)
-    params = api.init_model(cfg, jax.random.PRNGKey(3))
-    from repro.models.mlp import moe
-
-    layer0 = jax.tree.map(lambda a: a[0], params["layers"])["moe"]
-    x = jax.random.normal(jax.random.PRNGKey(4), (2, 32, cfg.d_model)) * 0.1
-    y0, _ = moe(cfg, layer0, x)
-    y1, _ = moe(cfg.replace(moe_dispatch_chunks=4), layer0, x)
-    # with generous capacity nothing is dropped in either mode
-    np.testing.assert_allclose(np.asarray(y0), np.asarray(y1), atol=1e-5)
-
-
-def test_moe_chunked_dispatch_grad_finite():
-    cfg = get_smoke_config("granite-moe-3b-a800m").replace(moe_dispatch_chunks=4)
-    params = api.init_model(cfg, jax.random.PRNGKey(5))
-    batch = {
-        "tokens": jnp.ones((2, 16), jnp.int32),
-        "labels": jnp.ones((2, 16), jnp.int32),
-    }
-    loss, grads = jax.value_and_grad(lambda p: api.model_loss(cfg, p, batch))(params)
-    assert np.isfinite(float(loss))
-    assert all(np.isfinite(np.asarray(g)).all() for g in jax.tree.leaves(grads))

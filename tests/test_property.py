@@ -13,7 +13,6 @@ from repro.core.plan2 import TwoWayPlan, global_pairs_of_block  # noqa: E402
 from repro.core.plan3 import ThreeWayPlan  # noqa: E402
 from repro.core.synthetic import analytic_window_vectors  # noqa: E402
 from repro.kernels.mgemm_levels.ref import mgemm_levels_ref  # noqa: E402
-from repro.optim.compression import dequantize, quantize  # noqa: E402
 
 DIMS = st.integers(2, 12)
 
@@ -131,16 +130,6 @@ def test_checksum_multiset_invariance(seed, n):
     v = rng.random(n).astype(np.float32)
     perm = rng.permutation(n)
     assert ck.checksum_pairs(i, j, v) == ck.checksum_pairs(i[perm], j[perm], v[perm])
-
-
-@settings(max_examples=20, deadline=None)
-@given(seed=st.integers(0, 2**31 - 1))
-def test_quantization_error_bound(seed):
-    rng = np.random.default_rng(seed)
-    x = jnp.asarray((rng.random(64) - 0.5).astype(np.float32) * 10)
-    q, s = quantize(x)
-    err = np.abs(np.asarray(dequantize(q, s) - x))
-    assert (err <= float(s) / 2 + 1e-7).all()
 
 
 @settings(max_examples=10, deadline=None)
